@@ -63,7 +63,7 @@ def _build_world():
     corpus = rng.integers(0, 64, size=(64, 32)).astype(np.int32)
     ds = DatastoreBuilder(dim=cfg.d_model, nlist=8, m=8, list_cap=512,
                           num_shards=2).from_corpus(params, cfg, corpus)
-    ccfg = ds.search_config(nprobe=4, k=8, backend="ref")
+    ccfg = ds.search_config(nprobe=4, k=8)
     rag = RagConfig(mode="knnlm", interval=1, k=8, lam=0.999,
                     temperature=1.0)
     return cfg, params, corpus, ds, ccfg, rag

@@ -18,8 +18,8 @@ XLA executable per flush — there is no dispatch structure left to
 measure, only XLA fusion luck — so the ref sweep is not the committed
 artifact.) On a CPU host the Pallas kernels run in interpret mode;
 relative cost there tracks grid-step count and per-step work, which is
-exactly what the fusion changes — on a real accelerator pass
-``interpret=False`` via the config.
+exactly what the fusion changes — on a real accelerator they compile
+(interpret mode is derived from the platform).
 
 Methodology notes (documented in the JSON meta):
   * batch sizes start at the service's wave scale (B >= 8) — sub-wave

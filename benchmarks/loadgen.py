@@ -393,7 +393,7 @@ def _build_gateway():
     corpus = np.stack(seqs, axis=1).astype(np.int32)
     ds = DatastoreBuilder(dim=cfg.d_model, nlist=8, m=8,
                           list_cap=512).from_corpus(params, cfg, corpus)
-    ccfg = ds.search_config(nprobe=4, k=8, backend="ref")
+    ccfg = ds.search_config(nprobe=4, k=8)
     rag = RagConfig(mode="knnlm", interval=1, k=8, lam=0.999,
                     temperature=1.0)
 

@@ -226,7 +226,7 @@ def fig12_measured_serving() -> List[Dict]:
     corpus = rng.integers(0, 64, size=(64, 32), dtype=np.int32)
     ds = DatastoreBuilder(dim=cfg.d_model, nlist=8, m=8,
                           list_cap=512).from_corpus(params, cfg, corpus)
-    ccfg = ds.search_config(nprobe=4, k=8, backend="ref")
+    ccfg = ds.search_config(nprobe=4, k=8)
 
     rows = []
     steps, batch, n_req = 16, 4, 2

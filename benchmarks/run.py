@@ -58,6 +58,8 @@ def main() -> None:
     ap.add_argument("--out", default=None,
                     help="output path for the sweep modes")
     args = ap.parse_args()
+    from repro.launch.cache import setup_compile_cache
+    setup_compile_cache()
 
     if args.mode == "retrieval":
         from benchmarks import retrieval_bench

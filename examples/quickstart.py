@@ -34,7 +34,7 @@ print(f"index: {ds.index_cfg.nlist} lists, {ds.num_shards} memory nodes, "
 
 # 3) search: scan the IVF index, stream PQ codes, merge truncated top-k'
 #    (through the Retriever protocol — same call the serving engine makes)
-ccfg = ds.search_config(nprobe=16, k=32, backend="ref")
+ccfg = ds.search_config(nprobe=16, k=32)
 queries = vecs[:32] + 0.02
 dists, ids = ds.retriever(ccfg).search(queries)
 
@@ -45,7 +45,8 @@ print(f"search: k'={ccfg.k_prime(4)} per node (K={ccfg.k}); "
       f"R10@{ccfg.k}={hits:.3f}")
 print("nearest ids[0]:", np.asarray(ids[0, :5]))
 
-# 5) the same search through the Pallas near-memory kernel (interpret mode)
+# 5) the same search through the Pallas near-memory kernel (interpreted on a
+#    CPU host, compiled on a TPU)
 ccfg_k = ds.search_config(nprobe=16, k=32, backend="pallas")
 d2, i2 = ds.retriever(ccfg_k).search(queries)
 print("pallas kernel agrees:", bool(jnp.allclose(dists, d2, rtol=1e-4)))

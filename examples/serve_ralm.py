@@ -76,7 +76,7 @@ num_shards = ret_devices if disaggregate else 2
 # datastore: hidden state of every prefix -> next token (kNN-LM, interval 1)
 ds = DatastoreBuilder(dim=cfg.d_model, nlist=8, m=8, list_cap=512,
                       num_shards=num_shards).from_corpus(params, cfg, corpus)
-ccfg = ds.search_config(nprobe=4, k=8, backend="ref")
+ccfg = ds.search_config(nprobe=4, k=8)
 print(f"datastore: {ds.num_vectors} vectors, {ds.num_shards} memory nodes, "
       f"k'={ccfg.k_prime(ds.num_shards)}")
 

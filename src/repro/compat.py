@@ -1,11 +1,8 @@
-"""jax version-compatibility shims.
+"""The three mesh helpers every sharded module shares.
 
-The codebase targets the jax >= 0.6 API surface (top-level ``shard_map``
-with ``check_vma``, ``jax.set_mesh``, ``jax.make_mesh(..., axis_types=)``)
-but must also run on the older jax baked into the CPU container
-(0.4.x: ``jax.experimental.shard_map`` with ``check_rep``, ``with mesh:``,
-no ``AxisType``). Every module that touches these APIs goes through here
-so the difference lives in exactly one place.
+Thin spellings of the jax APIs the code targets (top-level
+``jax.shard_map``, ``jax.set_mesh``, ``jax.make_mesh`` with explicit
+axis types), kept in one place so call sites agree on the defaults.
 """
 from __future__ import annotations
 
@@ -13,39 +10,22 @@ from typing import Sequence, Tuple
 
 import jax
 
-try:  # jax >= 0.6 exposes shard_map at top level
-    from jax import shard_map as _shard_map
-    _SHARD_MAP_KW = "check_vma"
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SHARD_MAP_KW = "check_rep"
-
-HAS_SET_MESH = hasattr(jax, "set_mesh")
-HAS_AXIS_TYPES = hasattr(jax.sharding, "AxisType")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = False):
-    """``jax.shard_map`` with the replication-check kwarg spelled per
-    version (``check_vma`` >= 0.6, ``check_rep`` before)."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_SHARD_MAP_KW: check_vma})
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def use_mesh(mesh: jax.sharding.Mesh):
-    """Context manager making ``mesh`` ambient: ``jax.set_mesh`` on new
-    jax; on old jax a ``Mesh`` is itself a context manager."""
-    if HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def make_mesh(shape: Sequence[int], axis_names: Tuple[str, ...],
               ) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the installed jax
-    distinguishes them (explicit-sharding jax versions default to
-    Explicit, which the shard_map code here does not want)."""
-    if HAS_AXIS_TYPES:
-        return jax.make_mesh(
-            tuple(shape), tuple(axis_names),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
-    return jax.make_mesh(tuple(shape), tuple(axis_names))
+    """``jax.make_mesh`` with Auto axis types (jax defaults to Explicit,
+    which the shard_map code here does not want)."""
+    return jax.make_mesh(
+        tuple(shape), tuple(axis_names),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))

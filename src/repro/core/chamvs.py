@@ -6,11 +6,10 @@ with pluggable backends routed through ``repro.kernels.registry``
 (``ChamVSConfig.kernel_spec()`` is the ``KernelSpec`` everything below
 here runs with):
 
-  ``backend="ref"``    — pure-jnp gather ADC (paper's CPU flavor; also what
-                          the multi-pod dry-run lowers, since Pallas does
-                          not compile on the CPU backend).
-  ``backend="pallas"`` — the near-memory Pallas kernels (interpret=True on
-                          CPU).
+  ``backend="ref"``    — pure-jnp gather ADC (paper's CPU flavor; the
+                          default on a CPU host).
+  ``backend="pallas"`` — the near-memory Pallas kernels (the default on an
+                          accelerator; interpreted on a CPU host).
 
 ``shard_search`` below is the *staged* per-shard pipeline — kept as the
 parity oracle for the fused path. The serving default
@@ -42,7 +41,7 @@ from jax.sharding import Mesh
 from repro.core import ivfpq
 from repro.core.approx_topk_math import truncated_queue_len
 from repro.core.ivfpq import IVFPQConfig, IVFPQParams, IVFPQShard
-from repro.kernels.registry import KernelSpec
+from repro.kernels.registry import KernelSpec, serving_spec
 
 
 @dataclasses.dataclass(frozen=True)
@@ -53,8 +52,9 @@ class ChamVSConfig:
     nprobe: int = 32
     k: int = 100
     eps: float = 0.01             # approx-queue failure budget (paper: 1%)
-    backend: str = "ref"          # "ref" | "pallas"
-    interpret: bool = True        # Pallas interpret mode (CPU container)
+    backend: Optional[str] = None  # "ref" | "pallas"; None = the
+    #                               platform's serving backend (Pallas
+    #                               compiled on an accelerator, ref on CPU)
     num_l1_blocks: int = 16       # producers per shard for the approx queue
     fused: bool = True            # ONE fused chamvs_scan dispatch over all
     #                               shards per wave; False keeps the staged
@@ -62,22 +62,22 @@ class ChamVSConfig:
 
     def kernel_spec(self) -> KernelSpec:
         """The registry ``KernelSpec`` this config routes kernels with —
-        the single place ``backend``/``interpret`` are interpreted."""
-        return KernelSpec(backend=self.backend, interpret=self.interpret)
+        the single place ``backend`` is interpreted. Interpret mode and
+        the fallback policy come from the platform (see
+        ``registry.serving_spec``)."""
+        return serving_spec(self.backend)
 
     def with_kernel(self, backend: Optional[str] = None,
-                    interpret: Optional[bool] = None,
                     fused: Optional[bool] = None) -> "ChamVSConfig":
         """Return a copy with the kernel selection overridden (``None``
         keeps the current value) — the one place the EngineConfig /
-        ServiceConfig ``kernel_backend`` / ``kernel_interpret`` /
-        ``kernel_fused`` knobs are folded in."""
-        if backend is None and interpret is None and fused is None:
+        ServiceConfig ``kernel_backend`` / ``kernel_fused`` knobs are
+        folded in."""
+        if backend is None and fused is None:
             return self
         return dataclasses.replace(
             self,
             backend=backend if backend is not None else self.backend,
-            interpret=interpret if interpret is not None else self.interpret,
             fused=fused if fused is not None else self.fused)
 
     def k_prime(self, num_shards: int) -> int:
@@ -87,6 +87,22 @@ class ChamVSConfig:
         the merge can always fill K slots."""
         return min(self.k, truncated_queue_len(self.k, max(1, num_shards),
                                                self.eps))
+
+
+def probe_lists(params: IVFPQParams, queries: jnp.ndarray,
+                cfg: ChamVSConfig) -> jnp.ndarray:
+    """ChamVS.idx: the nprobe closest IVF lists per query [nq, nprobe].
+    Shared by the fused, staged and mesh-routed scans (their parity
+    requires identical probes), routed through the registry frontend
+    when the config runs the Pallas kernels."""
+    spec = cfg.kernel_spec()
+    if spec.backend == "pallas":
+        from repro.kernels.ivf_scan.ops import ivf_index_scan
+        _, probe_ids = ivf_index_scan(queries, params.coarse_centroids,
+                                      cfg.nprobe, spec=spec)
+    else:
+        _, probe_ids = ivfpq.scan_ivf_index(params, queries, cfg.nprobe)
+    return probe_ids
 
 
 # ---------------------------------------------------------------------------
@@ -109,7 +125,8 @@ def shard_search(params: IVFPQParams, shard: IVFPQShard, queries: jnp.ndarray,
     ids = shard.ids[probe_ids]                                   # [nq,np,cap]
     lens = shard.list_len[probe_ids]                             # [nq,np]
 
-    if cfg.backend == "pallas":
+    spec = cfg.kernel_spec()
+    if spec.backend == "pallas":
         from repro.kernels.pq_adc.ops import pq_adc_topk
         B = nq * nprobe
         d_l, i_l = pq_adc_topk(
@@ -117,7 +134,7 @@ def shard_search(params: IVFPQParams, shard: IVFPQShard, queries: jnp.ndarray,
             codes.reshape(B, icfg.list_cap, icfg.m),
             lens.reshape(B),
             k=min(kk, icfg.list_cap),
-            spec=cfg.kernel_spec())
+            spec=spec)
         # local row idx -> global vector id via the per-list id table
         gid = jnp.take_along_axis(
             ids.reshape(B, icfg.list_cap),

@@ -112,6 +112,16 @@ def encode(params: IVFPQParams, vecs: jnp.ndarray, cfg: IVFPQConfig,
     return codes.T.astype(jnp.uint8), assign
 
 
+def list_sizes(params: IVFPQParams, vecs: np.ndarray, nlist: int,
+               batch: int = 65536) -> np.ndarray:
+    """[nlist] vectors per IVF list under the coarse quantizer."""
+    counts = np.zeros((nlist,), np.int64)
+    for s in range(0, vecs.shape[0], batch):
+        a = np.asarray(assign_coarse(params, jnp.asarray(vecs[s:s + batch])))
+        counts += np.bincount(a, minlength=nlist)
+    return counts
+
+
 def build_shards(
     params: IVFPQParams,
     vecs: np.ndarray,

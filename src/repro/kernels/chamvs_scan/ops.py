@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import registry
+from repro.kernels.common import query_tile
 from repro.kernels.chamvs_scan import kernel as _k
 from repro.kernels.chamvs_scan import ref as _ref
 
@@ -34,10 +35,35 @@ def chamvs_scan(luts: jnp.ndarray, codes: jnp.ndarray, gids: jnp.ndarray,
     spec = registry.resolve("chamvs_scan", spec)
     nq = codes.shape[1]
     if spec.backend == "pallas":
-        return _k.fused_scan(luts, codes, gids, lens, kk,
-                             tile_q=spec.pick_tile_q(nq),
-                             interpret=spec.interpret)
+        tile, nq_pad = query_tile(nq, spec.tile_q or 8)
+        if nq_pad > nq:     # pad rows scan empty lists: (+inf, -1) out
+            pad = nq_pad - nq
+            luts = _pad_axis(luts, 0, pad, 0)
+            codes = _pad_axis(codes, 1, pad, 0)
+            gids = _pad_axis(gids, 1, pad, -1)
+            lens = _pad_axis(lens, 1, pad, 0)
+        d, i = _k.fused_scan(luts, codes, gids, lens, kk, tile_q=tile,
+                             interpret=spec.use_interpret())
+        return d[:, :nq], i[:, :nq]
     return _jit_ref(luts, codes, gids, lens, kk=kk)
+
+
+def _pad_axis(x: jnp.ndarray, axis: int, n: int, value) -> jnp.ndarray:
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, n)
+    return jnp.pad(x, widths, constant_values=value)
+
+
+def probed_operands(params, stacked, queries: jnp.ndarray,
+                    probe_ids: jnp.ndarray, cfg):
+    """The per-(query, probed list) operands of one ``chamvs_scan`` call:
+    LUTs [nq, np, m, ksub] plus every shard's probed codes
+    [S, nq, np, cap, m], ids [S, nq, np, cap] and lengths [S, nq, np],
+    gathered from a ``stack_shards``-packed stack."""
+    from repro.core import ivfpq
+    luts = ivfpq.compute_luts(params, queries, probe_ids, cfg.ivfpq)
+    return (luts, stacked.codes[:, probe_ids], stacked.ids[:, probe_ids],
+            stacked.list_len[:, probe_ids])
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "kk", "spec"))
@@ -55,10 +81,6 @@ def fused_shard_scan(params, stacked, queries: jnp.ndarray,
     queries [nq, D] | probe_ids [nq, np]
     -> (dists [S, nq, kk], global ids [S, nq, kk]).
     """
-    from repro.core import ivfpq
-    luts = ivfpq.compute_luts(params, queries, probe_ids, cfg.ivfpq)
-    codes = stacked.codes[:, probe_ids]         # [S, nq, np, cap, m]
-    gids = stacked.ids[:, probe_ids]            # [S, nq, np, cap]
-    lens = stacked.list_len[:, probe_ids]       # [S, nq, np]
-    return chamvs_scan(luts, codes, gids, lens, kk,
+    operands = probed_operands(params, stacked, queries, probe_ids, cfg)
+    return chamvs_scan(*operands, kk,
                        spec=spec if spec is not None else cfg.kernel_spec())

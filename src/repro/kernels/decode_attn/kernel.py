@@ -6,14 +6,14 @@ axis* every step. This kernel is the dataflow-faithful replacement (the
 LM-side twin of ``chamvs_scan``'s streaming K-selection):
 
   * grid ``(B // tile_b, S // blk)`` — the trailing **kv-block axis** is
-    the streaming axis: each step pulls one ``[tile_b, blk, KV, D]``
+    the streaming axis: each step pulls one ``[tile_b, blk, KV*D]``
     K/V block HBM->VMEM and folds it into an online-softmax accumulator
-    carried in the *output refs* (their index_map ignores the kv-block
-    index, the same scratch-residency trick ``chamvs_scan`` uses for
-    its running top-k'), so the ``[B, H, S]`` score row never exists;
-  * **GQA-native**: queries arrive pre-grouped as ``[B, KV, G, D]`` and
-    scores contract directly against the KV-head axis — no
-    ``_repeat_kv`` materialization anywhere;
+    held in VMEM scratch across the kv-block axis, so the ``[B, H, S]``
+    score row never exists;
+  * **GQA-native**: each query head is laid out block-diagonally over
+    the merged ``KV*D`` lane axis (zero outside its KV group's lanes),
+    so scores contract directly against the cache — no ``_repeat_kv``
+    materialization anywhere;
   * **length-aware**: per-block validity is derived from each row's
     absolute ``position`` (linear slot ``i`` holds position ``i``; ring
     slot ``i`` holds ``pos - ((pos - i) mod S)``; sliding ``window``
@@ -31,10 +31,11 @@ after it wraps — at which point ``max(position) >= S - 1`` keeps every
 block live).
 
 Validated against the grouped ``ref`` oracle and the legacy einsum path
-in ``tests/test_decode_attn.py`` (hypothesis property test). The
-in-kernel einsums lower via ``dot_general`` with (row, kv-head) batch
-dims; on the CPU containers this runs in interpret mode (parity
-harness), compiled on a real accelerator.
+in ``tests/test_decode_attn.py`` (hypothesis property test). Both
+in-kernel einsums have one batch dim (the wave row), which is what
+Mosaic's matmul takes, and per-row positions come in through SMEM
+scalar prefetch; ``tests/test_chip_compile.py`` compiles the kernel for
+a described v5e. On a CPU host it runs in interpret mode.
 """
 from __future__ import annotations
 
@@ -43,65 +44,76 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
 
-def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref,
-                        out_ref, m_ref, l_ref, *,
-                        blk: int, s_real: int, window: int, ring: bool):
+def _decode_attn_kernel(pos_ref, q_ref, k_ref, v_ref, out_ref,
+                        acc_ref, m_ref, l_ref, *, tile_b: int, blk: int,
+                        s_real: int, window: int, ring: bool, scale: float):
+    i = pl.program_id(0)
     j = pl.program_id(1)
     nb = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    pos = pos_ref[:, 0]                                   # [tile_b]
+    # per-row positions live in SMEM (scalar prefetch): a [tile_b, 1]
+    # VMEM block would break the (8, 128) tiling for odd wave tiles
+    rows = [pos_ref[i * tile_b + r] for r in range(tile_b)]
     start = j * blk
     # tile-level skip: every slot in this block invalid for every row
-    live = start <= jnp.max(pos)
+    live = start <= functools.reduce(jnp.maximum, rows)
     if window > 0 and not ring:
         # linear cache + sliding window: blocks wholly below the tile's
         # min window edge are dead too (the window slid past them)
-        live = jnp.logical_and(live, start + blk - 1 > jnp.min(pos) - window)
+        live = jnp.logical_and(
+            live, start + blk - 1 > functools.reduce(jnp.minimum, rows)
+            - window)
 
     @pl.when(live)
     def _block():
-        q = q_ref[...].astype(jnp.float32)                # [tile_b,KV,G,D]
-        k = k_ref[...].astype(jnp.float32)                # [tile_b,blk,KV,D]
+        q = q_ref[...].astype(jnp.float32)                # [tile_b, H, E]
+        k = k_ref[...].astype(jnp.float32)                # [tile_b, blk, E]
         v = v_ref[...].astype(jnp.float32)
-        scale = q.shape[-1] ** -0.5
-        s = jnp.einsum("bkgd,bskd->bkgs", q, k,
+        # q is block-diagonal over the KV-head lane groups (see
+        # ``fused_decode_attention``), so contracting the whole
+        # KV*D lane axis gives each head its own group's scores: one
+        # batch dim, which is all Mosaic's matmul takes
+        s = jnp.einsum("bhe,bse->bhs", q, k,
                        preferred_element_type=jnp.float32) * scale
-        tile_b = pos.shape[0]
-        slot = start + jax.lax.broadcasted_iota(jnp.int32, (tile_b, blk), 1)
+        shape = s.shape                                   # [tile_b, H, blk]
+        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        pos = jnp.zeros(shape, jnp.int32)
+        for r, p_r in enumerate(rows):
+            pos = jnp.where(row == r, p_r, pos)
+        slot = start + jax.lax.broadcasted_iota(jnp.int32, shape, 2)
         if ring:
-            p_slot = pos[:, None] - ((pos[:, None] - slot) % s_real)
+            p_slot = pos - ((pos - slot) % s_real)
             valid = p_slot >= 0
         else:
             p_slot = slot
-            valid = p_slot <= pos[:, None]
+            valid = p_slot <= pos
         if window > 0:
-            valid &= p_slot > pos[:, None] - window
-        vmask = valid[:, None, None, :]                   # [tile_b,1,1,blk]
-        s = jnp.where(vmask, s, NEG_INF)
-        m_prev = m_ref[...]                               # [tile_b,KV,G]
-        m_new = jnp.maximum(m_prev, s.max(-1))
+            valid &= p_slot > pos - window
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]                               # [tile_b, H, 1]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.where(vmask, jnp.exp(s - m_new[..., None]), 0.0)
-        l_ref[...] = l_ref[...] * corr + p.sum(-1)
-        pv = jnp.einsum("bkgs,bskd->bkgd", p, v,
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
+        pv = jnp.einsum("bhs,bse->bhe", p, v,
                         preferred_element_type=jnp.float32)
-        out_ref[...] = out_ref[...] * corr[..., None] + pv
+        acc_ref[...] = acc_ref[...] * corr + pv
         m_ref[...] = m_new
 
     @pl.when(j == nb - 1)
     def _final():
-        out_ref[...] = out_ref[...] / jnp.maximum(
-            l_ref[...][..., None], 1e-20)
+        out_ref[...] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "ring", "tile_b",
@@ -116,37 +128,51 @@ def fused_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     q [B, 1, H, D] | k_cache/v_cache [B, S, KV, D] | position [B] int32
     -> [B, 1, H, D]. ``tile_b`` must divide B and ``blk`` must divide S
     (the frontend picks legal tiles via the registry heuristics).
+
+    Layout: the caches are read as ``[B, S, KV*D]`` (the KV-head and
+    head-dim axes merged into one lane axis), and q is expanded to a
+    block-diagonal ``[B, H, KV*D]`` whose head ``h`` row is zero outside
+    its KV group's D lanes. Both contractions are then plain
+    one-batch-dim matmuls, and the kernel returns each head's output in
+    its group's lanes, which the wrapper folds back to ``[B, H, D]``.
     """
     B, S, KV, D = k_cache.shape
     H = q.shape[2]
     G = H // KV
+    E = KV * D
     assert B % tile_b == 0 and S % blk == 0, (B, tile_b, S, blk)
-    qg = q[:, 0].reshape(B, KV, G, D)
-    pos = jnp.asarray(position, jnp.int32).reshape(B, 1)
-    kernel = functools.partial(_decode_attn_kernel, blk=blk, s_real=S,
-                               window=window, ring=ring)
-    grid = (B // tile_b, S // blk)
-    out, _, _ = pl.pallas_call(
+    group = jnp.arange(H) // G                                  # [H]
+    onehot = (group[:, None] == jnp.arange(KV)[None, :])        # [H, KV]
+    q_bd = (q[:, 0, :, None, :] *
+            onehot[None, :, :, None].astype(q.dtype)).reshape(B, H, E)
+    k2 = k_cache.reshape(B, S, E)
+    v2 = v_cache.reshape(B, S, E)
+    pos = jnp.asarray(position, jnp.int32).reshape(B)
+    kernel = functools.partial(_decode_attn_kernel, tile_b=tile_b, blk=blk,
+                               s_real=S, window=window, ring=ring,
+                               scale=D ** -0.5)
+    out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_b, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_b, KV, G, D), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((tile_b, blk, KV, D), lambda i, j: (i, j, 0, 0)),
-            pl.BlockSpec((tile_b, blk, KV, D), lambda i, j: (i, j, 0, 0)),
-        ],
-        out_specs=(
-            # index_map ignores j: the online-softmax state (acc, m, l)
-            # is carried in the output refs across the kv-block axis
-            pl.BlockSpec((tile_b, KV, G, D), lambda i, j: (i, 0, 0, 0)),
-            pl.BlockSpec((tile_b, KV, G), lambda i, j: (i, 0, 0)),
-            pl.BlockSpec((tile_b, KV, G), lambda i, j: (i, 0, 0)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((B, KV, G, D), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, G), jnp.float32),
-            jax.ShapeDtypeStruct((B, KV, G), jnp.float32),
-        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B // tile_b, S // blk),
+            in_specs=[
+                pl.BlockSpec((tile_b, H, E), lambda i, j, p: (i, 0, 0)),
+                pl.BlockSpec((tile_b, blk, E), lambda i, j, p: (i, j, 0)),
+                pl.BlockSpec((tile_b, blk, E), lambda i, j, p: (i, j, 0)),
+            ],
+            # index_map ignores j: the output block is written once, at
+            # the last kv block; (acc, m, l) live in VMEM scratch
+            out_specs=pl.BlockSpec((tile_b, H, E), lambda i, j, p: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((tile_b, H, E), jnp.float32),
+                            pltpu.VMEM((tile_b, H, 1), jnp.float32),
+                            pltpu.VMEM((tile_b, H, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, E), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(pos, qg, k_cache, v_cache)
-    return out.reshape(B, 1, H, D).astype(v_cache.dtype)
+    )(pos, q_bd, k2, v2)
+    # fold each head's own KV-group lanes back out of the [H, KV*D] rows
+    out = jnp.einsum("bhkd,hk->bhd", out.reshape(B, H, KV, D),
+                     onehot.astype(out.dtype))
+    return out[:, None].astype(v_cache.dtype)

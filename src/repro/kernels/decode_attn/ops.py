@@ -42,7 +42,7 @@ def pallas_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     return _k.fused_decode_attention(
         q, k_cache, v_cache, position, window=window, ring=ring,
         tile_b=spec.pick_tile_q(B), blk=spec.pick_block_seq(S),
-        interpret=spec.interpret)
+        interpret=spec.use_interpret())
 
 
 def count_skipped_blocks(positions: np.ndarray, S: int, blk: int,

@@ -15,11 +15,12 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import extract_topk_rows
+from repro.kernels.common import LANES, merge_topk_rows, round_up
 
 
-def _ivf_scan_kernel(q_ref, ct_ref, c2_ref, out_d_ref, out_i_ref, *,
+def _ivf_scan_kernel(q_ref, c_ref, c2_ref, out_d_ref, out_i_ref, *,
                      tile_q: int, tile_c: int, nprobe: int):
     ci = pl.program_id(1)
 
@@ -29,19 +30,18 @@ def _ivf_scan_kernel(q_ref, ct_ref, c2_ref, out_d_ref, out_i_ref, *,
         out_i_ref[...] = jnp.full_like(out_i_ref, -1)
 
     q = q_ref[...]                                             # [tile_q, D]
-    ct = ct_ref[...]                                           # [D, tile_c]
+    c = c_ref[...]                                             # [tile_c, D]
     # dist = ||q||^2 - 2 q.c + ||c||^2 ; the ||q||^2 term is rank-invariant
     # per row but kept so returned values equal true L2^2 distances.
     scores = jax.lax.dot_general(
-        q, ct, (((1,), (0,)), ((), ())),
+        q, c, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)                    # MXU
     q2 = jnp.sum(q * q, axis=-1, keepdims=True)
     d = q2 - 2.0 * scores + c2_ref[...]                        # [tile_q, tile_c]
 
     col = ci * tile_c + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    cand_d = jnp.concatenate([out_d_ref[...], d], axis=1)
-    cand_i = jnp.concatenate([out_i_ref[...], col], axis=1)
-    top_d, top_i = extract_topk_rows(cand_d, cand_i, nprobe)
+    top_d, top_i = merge_topk_rows(out_d_ref[...], out_i_ref[...], d, col,
+                                   nprobe)
     out_d_ref[...] = top_d
     out_i_ref[...] = top_i
 
@@ -58,26 +58,30 @@ def ivf_scan(queries: jnp.ndarray, centroids: jnp.ndarray, nprobe: int,
     tile_q = min(tile_q, nq)
     tile_c = min(tile_c, nlist)
     assert nq % tile_q == 0 and nlist % tile_c == 0, (nq, tile_q, nlist, tile_c)
-    ct = centroids.T.astype(jnp.float32)                       # [D, nlist]
     c2 = jnp.sum(centroids.astype(jnp.float32) ** 2, axis=-1)[None, :]
+
+    kq = round_up(nprobe, LANES)   # lane-aligned running queue width
 
     kernel = functools.partial(_ivf_scan_kernel, tile_q=tile_q, tile_c=tile_c,
                                nprobe=nprobe)
-    return pl.pallas_call(
+    out_d, out_i = pl.pallas_call(
         kernel,
         grid=(nq // tile_q, nlist // tile_c),
         in_specs=[
             pl.BlockSpec((tile_q, D), lambda qi, ci: (qi, 0)),
-            pl.BlockSpec((D, tile_c), lambda qi, ci: (0, ci)),
+            pl.BlockSpec((tile_c, D), lambda qi, ci: (ci, 0)),
             pl.BlockSpec((1, tile_c), lambda qi, ci: (0, ci)),
         ],
         out_specs=(
-            pl.BlockSpec((tile_q, nprobe), lambda qi, ci: (qi, 0)),
-            pl.BlockSpec((tile_q, nprobe), lambda qi, ci: (qi, 0)),
+            pl.BlockSpec((tile_q, kq), lambda qi, ci: (qi, 0)),
+            pl.BlockSpec((tile_q, kq), lambda qi, ci: (qi, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((nq, nprobe), jnp.float32),
-            jax.ShapeDtypeStruct((nq, nprobe), jnp.int32),
+            jax.ShapeDtypeStruct((nq, kq), jnp.float32),
+            jax.ShapeDtypeStruct((nq, kq), jnp.int32),
         ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(queries.astype(jnp.float32), ct, c2)
+    )(queries.astype(jnp.float32), centroids.astype(jnp.float32), c2)
+    return out_d[:, :nprobe], out_i[:, :nprobe]

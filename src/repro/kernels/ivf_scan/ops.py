@@ -11,8 +11,10 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
 from repro.kernels import registry
+from repro.kernels.common import query_tile
 from repro.kernels.ivf_scan import kernel as _k
 from repro.kernels.ivf_scan import ref as _ref
 
@@ -46,8 +48,10 @@ def ivf_index_scan(queries, centroids, nprobe: int,
                 f"nlist={nlist} < PALLAS_MIN_NLIST={PALLAS_MIN_NLIST}",
                 spec)
         else:
-            return _k.ivf_scan(queries, centroids, nprobe,
-                               tile_q=spec.pick_tile_q(nq),
+            tile, nq_pad = query_tile(nq, spec.tile_q or 8)
+            q = jnp.pad(queries, ((0, nq_pad - nq), (0, 0)))
+            d, i = _k.ivf_scan(q, centroids, nprobe, tile_q=tile,
                                tile_c=spec.pick_tile_c(nlist),
-                               interpret=spec.interpret)
+                               interpret=spec.use_interpret())
+            return d[:nq], i[:nq]
     return _jit_ref(queries, centroids, nprobe=nprobe)

@@ -68,7 +68,7 @@ def pq_adc_topk(
     codes = _pad_to(codes, 1, tile)
     if spec.backend == "pallas":
         return _k.adc_scan(luts, codes, lens, k, tile_n=tile,
-                           interpret=spec.interpret)
+                           interpret=spec.use_interpret())
     return _jit_ref_topk(luts, codes, lens, k=k)
 
 
@@ -94,7 +94,7 @@ def pq_shared_scan(
     codes_p = _pad_to(codes, 0, tile)
     if spec.backend == "pallas":
         out = _k.shared_scan(luts, codes_p, tile_n=tile,
-                             interpret=spec.interpret)
+                             interpret=spec.use_interpret())
     else:
         out = _jit_ref_shared(luts, codes_p)
     return out[:n]

@@ -37,6 +37,8 @@ import dataclasses
 import warnings
 from typing import Dict, Optional, Tuple
 
+import jax
+
 _BACKENDS = ("pallas", "ref", "einsum")
 _FALLBACK_POLICIES = ("warn", "silent", "error")
 
@@ -60,7 +62,9 @@ class KernelSpec:
     ChamVS frontends treat any non-"pallas" backend as "ref"."""
 
     backend: str = "pallas"        # "pallas" | "ref" | "einsum"
-    interpret: bool = True         # Pallas interpret mode (CPU containers)
+    interpret: Optional[bool] = None  # Pallas interpret mode; None =
+    #                                   derived from the platform (on only
+    #                                   where the default backend is CPU)
     tile_q: Optional[int] = None   # query-tile rows (None = heuristic)
     tile_n: Optional[int] = None   # scan-axis tile (None = heuristic)
     tile_c: Optional[int] = None   # centroid-tile cols (None = heuristic)
@@ -73,6 +77,14 @@ class KernelSpec:
         if self.fallback not in _FALLBACK_POLICIES:
             raise ValueError(f"unknown fallback policy {self.fallback!r}; "
                              f"expected one of {_FALLBACK_POLICIES}")
+
+    def use_interpret(self) -> bool:
+        """Interpret mode for this call: the explicit setting, else on
+        exactly when JAX's default backend is the CPU (Pallas only
+        interprets there; on an accelerator it compiles)."""
+        if self.interpret is not None:
+            return self.interpret
+        return on_cpu()
 
     # -- tile heuristics (the old per-frontend divisor searches) ------------
 
@@ -126,7 +138,26 @@ class KernelSpec:
 
 #: the two specs almost every call site wants
 REF = KernelSpec(backend="ref")
+PALLAS = KernelSpec(backend="pallas")        # interpret derived per call
 PALLAS_INTERPRET = KernelSpec(backend="pallas", interpret=True)
+
+
+def on_cpu() -> bool:
+    """True where JAX's default backend is the CPU (tests, this repo's
+    CPU containers). Asked at call time, never at import."""
+    return jax.default_backend() == "cpu"
+
+
+def serving_spec(backend: Optional[str] = None) -> KernelSpec:
+    """``KernelSpec`` of one serving-path kernel. With no ``backend``
+    override the platform decides: the compiled Pallas kernels with
+    ``fallback="error"`` on an accelerator, so a route to a reference
+    path raises; the reference paths on a CPU host, where Pallas could
+    only interpret (and a requested Pallas route warns on fallback, as
+    the tests expect)."""
+    cpu = on_cpu()
+    return KernelSpec(backend=backend or ("ref" if cpu else "pallas"),
+                      fallback="warn" if cpu else "error")
 
 
 # ---------------------------------------------------------------------------
@@ -192,7 +223,7 @@ def record_fallback(op: str, reason: str,
 def resolve(op: str, spec: Optional[KernelSpec],
             backend: Optional[str] = None,
             interpret: Optional[bool] = None,
-            default: KernelSpec = PALLAS_INTERPRET) -> KernelSpec:
+            default: KernelSpec = PALLAS) -> KernelSpec:
     """Fold a frontend's arguments into one ``KernelSpec``.
 
     ``spec`` wins when given; the legacy ``backend=``/``interpret=``

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import extract_topk_rows
+from repro.kernels.common import merge_topk_rows
 
 
 def _l1_kernel(d_ref, out_d_ref, out_i_ref, *, tile: int, k_prime: int,
@@ -23,7 +23,10 @@ def _l1_kernel(d_ref, out_d_ref, out_i_ref, *, tile: int, k_prime: int,
     t = pl.program_id(1)
     d = d_ref[...]                                           # [rows, tile]
     col = t * tile + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
-    top_d, top_i = extract_topk_rows(d, col, k_prime)
+    empty = (rows, k_prime)
+    top_d, top_i = merge_topk_rows(jnp.full(empty, jnp.inf, d.dtype),
+                                   jnp.full(empty, -1, jnp.int32), d, col,
+                                   k_prime)
     out_d_ref[...] = top_d[:, None, :]
     out_i_ref[...] = top_i[:, None, :]
 

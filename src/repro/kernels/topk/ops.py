@@ -66,5 +66,5 @@ def approx_topk(
     if spec.backend == "pallas":
         return _k.hierarchical_topk(d, k, k_prime, num_blocks,
                                     row_tile=spec.pick_tile_q(B),
-                                    interpret=spec.interpret)
+                                    interpret=spec.use_interpret())
     return _jit_ref_hier(d, k=k, num_blocks=num_blocks, k_prime=k_prime)

@@ -1,10 +1,12 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> [...]``.
 
-Builds a small RALM deployment end-to-end on the local devices through
-the unified ``repro.serve`` API: a ``DatastoreBuilder`` indexes a
-synthetic datastore, an ``EngineConfig`` picks monolithic (one mesh) or
-disaggregated (LM pool + retrieval pool) deployment, and the engine's
-scheduler pipelines the request batches.
+Builds a RALM deployment end-to-end on the local devices through the
+unified ``repro.serve`` API: a ``DatastoreBuilder`` indexes a synthetic
+datastore (full width by default, ``--reduced`` for the toy config), an
+``EngineConfig`` picks monolithic (one mesh) or disaggregated (LM pool +
+retrieval pool) deployment, and the engine's scheduler pipelines the
+request batches. On an accelerator the serving path runs the compiled
+Pallas kernels; on a CPU host the reference paths.
 """
 from __future__ import annotations
 
@@ -16,32 +18,62 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch
+from repro.launch.cache import setup_compile_cache
 from repro.serve import (DatastoreBuilder, EngineConfig, RalmEngine,
                          RalmRequest)
 
 
-def build_datastore(params, cfg, rng, n_docs=64, doc_len=32, num_shards=2):
-    """kNN-LM datastore over a synthetic corpus. Returns a
-    ``repro.serve.Datastore`` (the build recipe itself lives in
-    ``DatastoreBuilder``)."""
-    corpus = rng.integers(0, cfg.vocab_size, size=(n_docs, doc_len),
+#: the full-width datastore: about 2^20 kNN-LM keys (8192 documents of
+#: 129 tokens) under paper Table 3's SYN-512 code shape — 512-d keys,
+#: m = 32 sub-quantizers of ksub = 256, 1024 IVF lists, nprobe 32
+FULL_DOCS, FULL_DOC_LEN, FULL_NLIST, FULL_M, FULL_NPROBE = \
+    8192, 129, 1024, 32, 32
+FULL_TRAIN = 1 << 16          # keys the quantizers are trained on
+
+
+def build_datastore(params, cfg, rag, *, seed: int, reduced: bool,
+                    num_shards: int):
+    """kNN-LM datastore over a synthetic corpus drawn from ``seed``, and
+    the search config that serves it. Returns ``(Datastore,
+    ChamVSConfig)`` (the build recipe itself lives in
+    ``DatastoreBuilder``).
+
+    ``reduced`` builds a toy index for the reduced model; otherwise the
+    full-width SYN-512 datastore above, with list capacity taken from
+    the data and the quantizers trained on ``FULL_TRAIN`` keys."""
+    rng = np.random.default_rng(seed)
+    if reduced:
+        corpus = rng.integers(0, cfg.vocab_size, size=(256, 32),
+                              dtype=np.int32)
+        builder = DatastoreBuilder(dim=cfg.d_model, nlist=128,
+                                   num_shards=num_shards)
+        ds = builder.from_corpus(params, cfg, corpus)
+        return ds, ds.search_config(nprobe=4, k=min(rag.k, 8))
+    corpus = rng.integers(0, cfg.vocab_size, size=(FULL_DOCS, FULL_DOC_LEN),
                           dtype=np.int32)
-    ds = DatastoreBuilder(dim=cfg.d_model, nlist=8,
-                          num_shards=num_shards).from_corpus(
-                              params, cfg, corpus)
-    return ds
+    builder = DatastoreBuilder(dim=cfg.d_model, nlist=FULL_NLIST, m=FULL_M,
+                               list_cap=None, num_shards=num_shards)
+    keys, nxt = builder.corpus_keys(params, cfg, corpus)
+    train = keys[rng.choice(keys.shape[0], FULL_TRAIN, replace=False)]
+    ds = builder.build(keys, payload_tokens=jnp.asarray(nxt),
+                       train_vectors=train)
+    return ds, ds.search_config(nprobe=FULL_NPROBE, k=rag.k)
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="dec_s")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the arch's reduced-width config with a toy "
+                         "datastore (default: the published widths and the "
+                         "full SYN-512 datastore)")
     ap.add_argument("--steps", type=int, default=16)
     ap.add_argument("--batch", type=int, default=2)
     ap.add_argument("--requests", type=int, default=2,
                     help="concurrent request batches (pipelined)")
     ap.add_argument("--disaggregate", action="store_true",
-                    help="split devices into LM + retrieval pools")
+                    help="split devices into LM + retrieval pools: one LM "
+                         "device, every other device a retrieval shard")
     ap.add_argument("--async-retrieval", action="store_true",
                     help="route searches through a RetrievalService "
                          "(wave coalescing + result cache)")
@@ -89,22 +121,18 @@ def main() -> None:
                          "default grows on demand")
     ap.add_argument("--kernel-backend", choices=["ref", "pallas"],
                     default=None,
-                    help="override the ChamVS scan kernel backend")
-    ap.add_argument("--no-interpret", action="store_true",
-                    help="run Pallas kernels compiled instead of in "
-                         "interpret mode (needs a real accelerator)")
+                    help="override the ChamVS scan kernel backend "
+                         "(default: pallas on an accelerator, ref on CPU)")
     ap.add_argument("--staged-scan", action="store_true",
                     help="per-shard staged scan pipeline (one chamvs "
                          "dispatch per shard; the parity oracle) instead "
                          "of the fused single-dispatch chamvs_scan")
     ap.add_argument("--attn-kernel", choices=["ref", "pallas", "einsum"],
                     default=None,
-                    help="wave decode-attention kernel: ref = grouped "
-                         "einsum over the KV-head axis (default, the CPU "
-                         "serving flavor), pallas = the streaming "
-                         "decode_attn kernel (pair with --no-interpret "
-                         "on a real accelerator), einsum = the legacy "
-                         "full-materialization oracle")
+                    help="wave decode-attention kernel (default: pallas "
+                         "= the streaming decode_attn kernel on an "
+                         "accelerator, ref = the grouped einsum on CPU); "
+                         "einsum = the legacy full-materialization oracle")
     ap.add_argument("--attn-seq-block", type=int, default=16,
                     help="KV-pool seq-axis alignment quantum: per-wave "
                          "attention reads crop to this multiple of the "
@@ -129,6 +157,7 @@ def main() -> None:
                     help="gateway bind address (with --gateway)")
     args = ap.parse_args()
 
+    setup_compile_cache()
     from repro.models import transformer as tf
     spec = get_arch(args.arch)
     cfg = spec.reduced if args.reduced else spec.model
@@ -136,11 +165,11 @@ def main() -> None:
     rng = np.random.default_rng(0)
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
 
-    disaggregate = args.disaggregate and len(jax.devices()) >= 2
-    ret_devices = min(2, len(jax.devices()) - 1) if disaggregate else 1
-    ds = build_datastore(params, cfg, rng,
-                         num_shards=ret_devices if disaggregate else 2)
-    ccfg = ds.search_config(nprobe=4, k=min(rag.k, 8), backend="ref")
+    disaggregate = args.disaggregate
+    ret_devices = len(jax.devices()) - 1 if disaggregate else 1
+    ds, ccfg = build_datastore(params, cfg, rag, seed=0,
+                               reduced=args.reduced,
+                               num_shards=ret_devices if disaggregate else 2)
 
     econfig = EngineConfig(model=cfg, rag=rag, disaggregate=disaggregate,
                            lm_devices=1, ret_devices=ret_devices,
@@ -152,13 +181,9 @@ def main() -> None:
                            wave_decode=not args.per_sequence,
                            kv_slots=args.kv_slots,
                            kernel_backend=args.kernel_backend,
-                           kernel_interpret=(False if args.no_interpret
-                                             else None),
                            kernel_fused=(False if args.staged_scan
                                          else None),
                            attn_backend=args.attn_kernel,
-                           attn_interpret=(False if args.no_interpret
-                                           else None),
                            attn_seq_block=args.attn_seq_block,
                            retrieval_deadline_s=(
                                args.retrieval_deadline_ms / 1e3),

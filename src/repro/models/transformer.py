@@ -500,6 +500,20 @@ def forward(params: Params, cfg: ModelConfig,
     return logits, caches
 
 
+def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray
+                  ) -> jnp.ndarray:
+    """Last-layer hidden states [B, T, d] of a causal forward over
+    ``tokens`` [B, T] — the ``return_hidden`` output of ``forward``
+    without the unembedding, so a corpus pass never materializes
+    [B, T, vocab] logits."""
+    h = embed_tokens(params, tokens)
+    B, T = h.shape[:2]
+    positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+    h, _ = apply_stack(cfg, params["classes"], h, positions, "train", None,
+                       None)
+    return h
+
+
 def decode_step(params: Params, cfg: ModelConfig, caches: Params,
                 token: jnp.ndarray, position: jnp.ndarray,
                 enc_states: Optional[jnp.ndarray] = None,

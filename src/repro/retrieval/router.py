@@ -25,9 +25,10 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.compat import shard_map, use_mesh
-from repro.core import ivfpq
-from repro.core.chamvs import (ChamVSConfig, shard_search, stack_shards)
+from repro.core.chamvs import (ChamVSConfig, probe_lists, shard_search,
+                               stack_shards)
 from repro.core.ivfpq import IVFPQParams, IVFPQShard
+from repro.kernels.chamvs_scan.ops import fused_shard_scan
 
 
 def num_db_shards(mesh: Mesh, db_axes: Tuple[str, ...]) -> int:
@@ -70,15 +71,22 @@ def build_search(
 
     def body(params: IVFPQParams, shard: IVFPQShard, queries: jnp.ndarray):
         # shard: leading axis length 1 on this device; queries: [nq_local, D]
-        local = jax.tree.map(lambda x: x[0], shard)
         nq_local = queries.shape[0]
-        _, probe_ids = ivfpq.scan_ivf_index(params, queries, cfg.nprobe)
+        probe_ids = probe_lists(params, queries, cfg)
         if probe_split:
             npl = cfg.nprobe // qsize
             col = jax.lax.axis_index(qa)
             probe_ids = jax.lax.dynamic_slice_in_dim(
                 probe_ids, col * npl, npl, axis=1)
-        d, i = shard_search(params, local, queries, probe_ids, cfg, kk)
+        if cfg.fused:
+            # the same one-dispatch scan the local pipeline runs, over
+            # this memory node's one-shard stack
+            d, i = fused_shard_scan(params, shard, queries, probe_ids,
+                                    cfg, kk)
+            d, i = d[0], i[0]
+        else:
+            local = jax.tree.map(lambda x: x[0], shard)
+            d, i = shard_search(params, local, queries, probe_ids, cfg, kk)
         # aggregate over memory nodes (paper step 7-8): gather the kk
         # survivors of every producer, then exact-merge.
         gather_axes = db_axes + ((qa,) if probe_split else ())
@@ -120,6 +128,7 @@ def build_search(
             assert n % qsize == 0, (n, qsize)
         return fn(params, stacked, queries)
 
+    search.query_spec = q_spec      # how the queries enter the mesh
     return search
 
 
@@ -175,8 +184,11 @@ class ShardRouter:
         qa = query_axis if (query_axis and
                             query_axis in mesh.axis_names) else None
         self.query_size = mesh.shape[qa] if qa else 1
-        self._search = jax.jit(build_search(mesh, cfg, db_axes=db_axes,
-                                            query_axis=query_axis, nq=nq))
+        search = build_search(mesh, cfg, db_axes=db_axes,
+                              query_axis=query_axis, nq=nq)
+        self._query_sharding = NamedSharding(mesh, search.query_spec)
+        self._replicated = NamedSharding(mesh, P())
+        self._search = jax.jit(search)
         self._gather = jax.jit(build_gather(mesh, db_axes))
 
     # -- placement ----------------------------------------------------------
@@ -210,12 +222,27 @@ class ShardRouter:
                               NamedSharding(self.mesh, P(self.db_axes)))
 
     # -- execution ----------------------------------------------------------
+    #
+    # The retrieval mesh is its own device set (disaggregated serving puts
+    # the LM on other chips), so every call hands its operands over
+    # explicitly: in to the mesh, and the results back to the devices
+    # the caller's array lived on.
+
+    @staticmethod
+    def _home(x) -> Optional[jax.sharding.Sharding]:
+        return x.sharding if isinstance(x, jax.Array) else None
 
     def search(self, params: IVFPQParams, stacked: IVFPQShard,
                queries: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+        home = self._home(queries)
+        q = jax.device_put(queries, self._query_sharding)
         with use_mesh(self.mesh):
-            return self._search(params, stacked, queries)
+            out = self._search(params, stacked, q)
+        return out if home is None else jax.device_put(out, home)
 
     def gather(self, table: jnp.ndarray, ids: jnp.ndarray) -> jnp.ndarray:
+        home = self._home(ids)
+        i = jax.device_put(ids, self._replicated)
         with use_mesh(self.mesh):
-            return self._gather(table, ids)
+            out = self._gather(table, i)
+        return out if home is None else jax.device_put(out, home)

@@ -35,12 +35,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import ivfpq
-from repro.core.chamvs import ChamVSConfig, shard_search, stack_shards
+from repro.core.chamvs import (ChamVSConfig, probe_lists, shard_search,
+                               stack_shards)
 from repro.obs.trace import NULL_TRACER
 from repro.core.ivfpq import IVFPQParams, IVFPQShard
 from repro.kernels.chamvs_scan.ops import fused_shard_scan
-from repro.kernels.ivf_scan.ops import ivf_index_scan
 from repro.retrieval import merge as merge_lib
 from repro.retrieval.cache import QueryCache
 from repro.retrieval.chaos import ChaosInjector, FaultPlan, ScanHang
@@ -77,9 +76,7 @@ class ServiceConfig:
     #                               times (off = maximum async overlap)
     kernel_backend: Optional[str] = None  # override ChamVSConfig.backend
     #                               ("ref" | "pallas") so serving configs
-    #                               can select the Pallas scan path
-    kernel_interpret: Optional[bool] = None  # override ChamVSConfig.
-    #                               interpret (Pallas interpret mode)
+    #                               can select the scan path
     kernel_fused: Optional[bool] = None  # override ChamVSConfig.fused:
     #                               one fused chamvs_scan dispatch per
     #                               wave (True) vs the staged per-shard
@@ -109,21 +106,6 @@ def next_pow2(n: int) -> int:
 # service instances and the `search_single` one-shot path)
 # ---------------------------------------------------------------------------
 
-def _probe_stage(params: IVFPQParams, queries: jnp.ndarray,
-                 cfg: ChamVSConfig) -> jnp.ndarray:
-    """ChamVS.idx: pick the nprobe closest IVF lists per query. Shared
-    by the fused and staged paths (parity requires identical probes),
-    routed through the registry frontend when the config asks for the
-    Pallas centroid scan."""
-    spec = cfg.kernel_spec()
-    if spec.backend == "pallas":
-        _, probe_ids = ivf_index_scan(queries, params.coarse_centroids,
-                                      cfg.nprobe, spec=spec)
-    else:
-        _, probe_ids = ivfpq.scan_ivf_index(params, queries, cfg.nprobe)
-    return probe_ids
-
-
 @functools.partial(jax.jit, static_argnames=("cfg", "kk"))
 def _scan_stage(params: IVFPQParams, shards: Tuple[IVFPQShard, ...],
                 queries: jnp.ndarray, *, cfg: ChamVSConfig, kk: int
@@ -133,7 +115,7 @@ def _scan_stage(params: IVFPQParams, shards: Tuple[IVFPQShard, ...],
     the parity oracle for ``_scan_stage_fused``.
 
     Returns stacked candidates (dists [S, nq, kk], ids [S, nq, kk])."""
-    probe_ids = _probe_stage(params, queries, cfg)
+    probe_ids = probe_lists(params, queries, cfg)
     per = [shard_search(params, s, queries, probe_ids, cfg, kk)
            for s in shards]
     return (jnp.stack([p[0] for p in per]),
@@ -149,7 +131,7 @@ def _scan_stage_fused(params: IVFPQParams, stacked: IVFPQShard,
     shard in the ``stack_shards``-packed stack — no materialized
     [B, n] distance matrix, no per-shard dispatch loop, no separate
     top-k pass. Same return contract as ``_scan_stage``."""
-    probe_ids = _probe_stage(params, queries, cfg)
+    probe_ids = probe_lists(params, queries, cfg)
     return fused_shard_scan(params, stacked, queries, probe_ids, cfg, kk)
 
 
@@ -395,13 +377,12 @@ class RetrievalService:
               cfg: ChamVSConfig, config: Optional[ServiceConfig] = None
               ) -> "RetrievalService":
         """Single-process service (tests, builds, monolithic serving).
-        ``ServiceConfig.kernel_backend`` / ``kernel_interpret`` override
-        the corresponding ``ChamVSConfig`` fields, so a deployment config
-        can select the Pallas scan path without rebuilding the search
-        config by hand."""
+        ``ServiceConfig.kernel_backend`` / ``kernel_fused`` override the
+        corresponding ``ChamVSConfig`` fields, so a deployment config
+        can select the scan path without rebuilding the search config by
+        hand."""
         if config is not None:
             cfg = cfg.with_kernel(config.kernel_backend,
-                                  config.kernel_interpret,
                                   config.kernel_fused)
         return cls(LocalPipeline(params, shards, cfg), config=config)
 
@@ -415,11 +396,10 @@ class RetrievalService:
         ``ServiceConfig`` kernel overrides cannot apply here — reject
         them loudly rather than silently serving ref-scan numbers."""
         if config is not None and (config.kernel_backend is not None or
-                                   config.kernel_interpret is not None or
                                    config.kernel_fused is not None):
             raise ValueError(
-                "ServiceConfig.kernel_backend/kernel_interpret/"
-                "kernel_fused cannot override a distributed pipeline — "
+                "ServiceConfig.kernel_backend/kernel_fused cannot "
+                "override a distributed pipeline — "
                 "the ShardRouter owns its ChamVSConfig; build the router "
                 "with cfg.with_kernel(...) instead")
         return cls(RouterPipeline(router, params, shards), config=config)

@@ -183,24 +183,22 @@ class EngineConfig:
     #                                      values defer admission until
     #                                      completions free slots
     kernel_backend: Optional[str] = None  # override ChamVSConfig.backend
-    #                                      ("ref" | "pallas") from the
-    #                                      deployment config
-    kernel_interpret: Optional[bool] = None  # override Pallas interpret
-    #                                      mode (CPU containers need True)
+    #                                      ("ref" | "pallas"); None = the
+    #                                      platform's serving backend
+    #                                      (compiled Pallas on an
+    #                                      accelerator, ref on CPU)
     kernel_fused: Optional[bool] = None  # override ChamVSConfig.fused:
     #                                      ONE chamvs_scan dispatch per
     #                                      retrieval wave (True) vs the
     #                                      staged per-shard oracle (False)
     attn_backend: Optional[str] = None   # wave decode-attention kernel:
-    #                                      None/"ref" = grouped einsum
-    #                                      over the KV-head axis (CPU
-    #                                      serving flavor), "pallas" =
-    #                                      the streaming decode_attn
-    #                                      kernel, "einsum" = the legacy
+    #                                      None = the platform's serving
+    #                                      backend ("pallas", the
+    #                                      streaming decode_attn kernel,
+    #                                      on an accelerator; "ref", the
+    #                                      grouped einsum, on CPU);
+    #                                      "einsum" = the legacy
     #                                      full-materialization oracle
-    attn_interpret: Optional[bool] = None  # Pallas interpret mode for
-    #                                      the decode-attn kernel (CPU
-    #                                      containers need True)
     trace: bool = False                  # enable the observability
     #                                      tracer (repro.obs): per-request
     #                                      spans across scheduler waves,

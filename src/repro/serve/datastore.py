@@ -18,6 +18,7 @@ implementations for either deployment shape.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import List, Optional, Tuple
 
 import jax
@@ -27,12 +28,23 @@ from jax.sharding import Mesh
 
 from repro.core.chamvs import ChamVSConfig
 from repro.core.ivfpq import (IVFPQConfig, IVFPQParams, IVFPQShard,
-                              build_shards, train_ivfpq)
+                              build_shards, list_sizes, train_ivfpq)
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
 from repro.retrieval.service import RetrievalService, ServiceConfig
 from repro.serve.api import (AsyncRetriever, DistributedRetriever,
                              LocalRetriever)
+
+
+CORPUS_BATCH_TOKENS = 1 << 16   # tokens per corpus forward when keying
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _corpus_hidden(params, cfg: ModelConfig, tokens: jnp.ndarray
+                   ) -> jnp.ndarray:
+    """[B, T] tokens -> [B, T - 1, d] f32 keys (every prefix but the
+    last, which has no next token)."""
+    return tf.hidden_states(params, cfg, tokens)[:, :-1].astype(jnp.float32)
 
 
 @dataclasses.dataclass
@@ -50,7 +62,7 @@ class Datastore:
         return len(self.shards)
 
     def search_config(self, nprobe: int = 32, k: int = 100,
-                      backend: str = "ref", **kw) -> ChamVSConfig:
+                      backend: Optional[str] = None, **kw) -> ChamVSConfig:
         return ChamVSConfig(ivfpq=self.index_cfg, nprobe=nprobe, k=k,
                             backend=backend, **kw)
 
@@ -96,11 +108,13 @@ class Datastore:
 class DatastoreBuilder:
     """Hyperparameters of the build, with the defaults the old call
     sites converged on. ``m=None`` derives the PQ sub-quantizer count
-    from the dimension (``dim // 16``, floor 4)."""
+    from the dimension (``dim // 16``, floor 4); ``list_cap=None``
+    derives the per-shard list capacity from the data (the longest
+    list slice, rounded up to 128 rows)."""
     dim: int
     nlist: int = 8
     m: Optional[int] = None
-    list_cap: int = 1024
+    list_cap: Optional[int] = 1024
     residual: bool = False
     num_shards: int = 2
     kmeans_iters: int = 8
@@ -109,7 +123,8 @@ class DatastoreBuilder:
     def index_config(self) -> IVFPQConfig:
         m = self.m if self.m is not None else max(self.dim // 16, 4)
         return IVFPQConfig(dim=self.dim, nlist=self.nlist, m=m,
-                           list_cap=self.list_cap, residual=self.residual)
+                           list_cap=self.list_cap or 0,
+                           residual=self.residual)
 
     def build(self, vectors: np.ndarray,
               payload_tokens: Optional[jnp.ndarray] = None,
@@ -126,6 +141,10 @@ class DatastoreBuilder:
         params = train_ivfpq(jax.random.PRNGKey(self.seed),
                              jnp.asarray(train), icfg,
                              kmeans_iters=self.kmeans_iters)
+        if self.list_cap is None:
+            longest = int(list_sizes(params, vectors, icfg.nlist).max())
+            cap = -(-longest // self.num_shards)
+            icfg = dataclasses.replace(icfg, list_cap=-(-cap // 128) * 128)
         shards = build_shards(params, vectors, icfg,
                               num_shards=self.num_shards)
         return Datastore(
@@ -142,14 +161,25 @@ class DatastoreBuilder:
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """kNN-LM keys: the LM's hidden state at every prefix of
         ``corpus`` [n_docs, doc_len], paired with the next token.
-        Returns (keys [N, d_model], next_tokens [N])."""
+        Returns (keys [N, d_model] f32, next_tokens [N]).
+
+        The corpus runs through one jitted hidden-state forward per
+        batch of about ``CORPUS_BATCH_TOKENS`` tokens (the last batch
+        padded to the same shape, so it compiles once); no logits are
+        formed."""
         corpus = np.asarray(corpus, np.int32)
-        _, _, hidden = tf.forward(params, cfg, tokens=jnp.asarray(corpus),
-                                  mode="train", return_hidden=True)
-        keys = np.asarray(hidden[:, :-1].astype(jnp.float32)).reshape(
-            -1, cfg.d_model)
+        n_docs, doc_len = corpus.shape
+        rows = max(1, min(n_docs, CORPUS_BATCH_TOKENS // doc_len))
+        keys = np.empty((n_docs, doc_len - 1, cfg.d_model), np.float32)
+        for s in range(0, n_docs, rows):
+            batch = corpus[s:s + rows]
+            n = batch.shape[0]
+            if n < rows:
+                batch = np.pad(batch, ((0, rows - n), (0, 0)))
+            keys[s:s + n] = np.asarray(
+                _corpus_hidden(params, cfg, jnp.asarray(batch)))[:n]
         nxt = corpus[:, 1:].reshape(-1)
-        return keys, nxt
+        return keys.reshape(-1, cfg.d_model), nxt
 
     def from_corpus(self, params, cfg: ModelConfig, corpus: np.ndarray
                     ) -> Datastore:

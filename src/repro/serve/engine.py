@@ -315,7 +315,6 @@ class RalmEngine:
                  max_active: Optional[int] = None,
                  wave: bool = True, kv_slots: Optional[int] = None,
                  attn_backend: Optional[str] = None,
-                 attn_interpret: Optional[bool] = None,
                  attn_seq_block: int = 16,
                  tracer: Optional[Tracer] = None,
                  speculate_k: int = 0,
@@ -328,20 +327,18 @@ class RalmEngine:
         slots; ``None`` lets the pool grow on demand.
 
         ``attn_backend`` selects the wave decode-attention kernel:
-        ``"ref"`` (default — grouped einsum over the KV-head axis, the
-        CPU serving flavor), ``"pallas"`` (the streaming
-        ``kernels/decode_attn`` kernel; interpret mode per
-        ``attn_interpret``, default True for CPU containers), or
-        ``"einsum"`` (the legacy full-materialization oracle — "kernel
-        off"). ``attn_seq_block`` is the pool's seq-axis alignment
+        ``None`` (default) is the platform's serving backend — the
+        streaming ``kernels/decode_attn`` Pallas kernel, compiled, on an
+        accelerator, and ``"ref"`` (grouped einsum over the KV-head
+        axis) on a CPU host; ``"pallas"`` on a CPU host interprets the
+        kernel; ``"einsum"`` is the legacy full-materialization oracle
+        ("kernel off"). ``attn_seq_block`` is the pool's seq-axis alignment
         quantum: per wave the engine crops attention reads to the
         block-aligned valid prefix (``KVCachePool.attn_len``), so short
         waves stop paying for pool padding at the cost of O(max_seq /
         attn_seq_block) extra decode-graph variants."""
         self.backend = backend
-        self.attn_spec = registry.KernelSpec(
-            backend=attn_backend if attn_backend is not None else "ref",
-            interpret=True if attn_interpret is None else attn_interpret)
+        self.attn_spec = registry.serving_spec(attn_backend)
         self.attn_seq_block = attn_seq_block
         self.retriever = retriever
         self.rag = rag if rag is not None else RagConfig(mode="none")
@@ -435,13 +432,12 @@ class RalmEngine:
                    max_seq: Optional[int] = None, wave: bool = True,
                    kv_slots: Optional[int] = None,
                    attn_backend: Optional[str] = None,
-                   attn_interpret: Optional[bool] = None,
                    attn_seq_block: int = 16,
                    speculate_k: int = 0,
                    speculate_verify: bool = True) -> "RalmEngine":
         return cls(MonolithicBackend(params, cfg), retriever, rag,
                    max_seq=max_seq, wave=wave, kv_slots=kv_slots,
-                   attn_backend=attn_backend, attn_interpret=attn_interpret,
+                   attn_backend=attn_backend,
                    attn_seq_block=attn_seq_block,
                    speculate_k=speculate_k,
                    speculate_verify=speculate_verify)
@@ -458,7 +454,6 @@ class RalmEngine:
                       measure: bool = True, wave: bool = True,
                       kv_slots: Optional[int] = None,
                       attn_backend: Optional[str] = None,
-                      attn_interpret: Optional[bool] = None,
                       attn_seq_block: int = 16) -> "RalmEngine":
         backend = DisaggregatedBackend(params, cfg, lm_devices=lm_devices,
                                        ret_devices=ret_devices,
@@ -469,7 +464,6 @@ class RalmEngine:
             query_proj=query_proj)
         return cls(backend, retriever, rag, max_seq=max_seq, wave=wave,
                    kv_slots=kv_slots, attn_backend=attn_backend,
-                   attn_interpret=attn_interpret,
                    attn_seq_block=attn_seq_block)
 
     @classmethod
@@ -478,31 +472,29 @@ class RalmEngine:
                     query_proj: Optional[jnp.ndarray] = None
                     ) -> "RalmEngine":
         """Stand an engine up from an ``EngineConfig`` + a built
-        ``Datastore`` (see ``repro.serve.datastore``). Falls back to a
-        monolithic engine (with a warning) when ``disaggregate`` is
-        requested on a single-device host."""
-        # plumb the search-kernel selection (Pallas vs ref, interpret
-        # mode, fused vs staged scan) from the deployment config down to
-        # ChamVSConfig — the registry KernelSpec everything routes with
+        ``Datastore`` (see ``repro.serve.datastore``). A disaggregated
+        config needs ``lm_devices + ret_devices`` devices and raises
+        when the host has fewer."""
+        # plumb the search-kernel selection (Pallas vs ref, fused vs
+        # staged scan) from the deployment config down to ChamVSConfig —
+        # the registry KernelSpec everything routes with
         search_cfg = search_cfg.with_kernel(config.kernel_backend,
-                                            config.kernel_interpret,
                                             config.kernel_fused)
-        if config.disaggregate and len(jax.devices()) < 2:
-            import warnings
-            warnings.warn(
-                "EngineConfig.disaggregate=True needs >= 2 devices; "
-                f"found {len(jax.devices())} — falling back to a "
-                "monolithic engine (no PoolTimes).", RuntimeWarning,
-                stacklevel=2)
-        if config.disaggregate and len(jax.devices()) >= 2 and \
-                config.async_retrieval:
+        if config.disaggregate:
+            need = config.lm_devices + config.ret_devices
+            if len(jax.devices()) < need:
+                raise ValueError(
+                    f"EngineConfig.disaggregate=True needs lm_devices + "
+                    f"ret_devices = {need} devices; found "
+                    f"{len(jax.devices())}")
+        if config.disaggregate and config.async_retrieval:
             import warnings
             warnings.warn(
                 "EngineConfig.async_retrieval is not wired into the "
                 "disaggregated path yet — falling back to the synchronous "
                 "DistributedRetriever (no RetrievalService coalescing or "
                 "cache).", RuntimeWarning, stacklevel=2)
-        if config.disaggregate and len(jax.devices()) >= 2:
+        if config.disaggregate:
             if config.speculate_k > 0:
                 import warnings
                 warnings.warn(
@@ -521,7 +513,6 @@ class RalmEngine:
                 max_seq=config.max_seq, wave=config.wave_decode,
                 kv_slots=config.kv_slots,
                 attn_backend=config.attn_backend,
-                attn_interpret=config.attn_interpret,
                 attn_seq_block=config.attn_seq_block)
         else:
             if config.retrieval_cache > 0 and not config.async_retrieval:
@@ -579,7 +570,6 @@ class RalmEngine:
                                  wave=config.wave_decode,
                                  kv_slots=config.kv_slots,
                                  attn_backend=config.attn_backend,
-                                 attn_interpret=config.attn_interpret,
                                  attn_seq_block=config.attn_seq_block,
                                  speculate_k=speculate_k,
                                  speculate_verify=config.speculate_verify)

@@ -191,7 +191,7 @@ def test_search_single_fused_equals_staged(small_index, backend):
 
 def _count_pallas_calls(jaxpr) -> int:
     """Recursively count pallas_call primitives in a (closed) jaxpr."""
-    import jax.core
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def walk(j):
         n = 0
@@ -201,9 +201,9 @@ def _count_pallas_calls(jaxpr) -> int:
             for v in eqn.params.values():
                 vs = v if isinstance(v, (list, tuple)) else (v,)
                 for x in vs:
-                    if isinstance(x, jax.core.ClosedJaxpr):
+                    if isinstance(x, ClosedJaxpr):
                         n += walk(x.jaxpr)
-                    elif isinstance(x, jax.core.Jaxpr):
+                    elif isinstance(x, Jaxpr):
                         n += walk(x)
         return n
 

@@ -217,6 +217,26 @@ def test_kernel_spec_validation_and_tiles():
     assert KernelSpec(tile_c=100).pick_tile_c(128) == 64
 
 
+def test_serving_spec_follows_platform(monkeypatch):
+    """Serving kernels: the reference paths on a CPU host (a Pallas
+    request interprets there); the compiled Pallas kernels on an
+    accelerator, where a route to a reference path raises."""
+    from repro.kernels import registry
+
+    s = registry.serving_spec()
+    assert (s.backend, s.fallback, s.use_interpret()) == ("ref", "warn", True)
+    assert registry.serving_spec("pallas").use_interpret()
+    monkeypatch.setattr(registry, "on_cpu", lambda: False)
+    s = registry.serving_spec()
+    assert (s.backend, s.fallback, s.use_interpret()) == \
+        ("pallas", "error", False)
+    q = jax.random.normal(jax.random.PRNGKey(14), (4, 16))
+    c = jax.random.normal(jax.random.PRNGKey(15), (16, 16))   # < 128 lists
+    with pytest.raises(registry.KernelFallbackError):
+        ivf_index_scan(q, c, 4, spec=s)
+    assert registry.fallback_count() == 0
+
+
 def test_explicit_nondivisor_tile_override_still_runs():
     q = jax.random.normal(jax.random.PRNGKey(12), (12, 16))
     c = jax.random.normal(jax.random.PRNGKey(13), (128, 16))

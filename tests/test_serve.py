@@ -95,6 +95,48 @@ def test_datastore_from_corpus_matches_manual(tiny_ralm):
     assert (np.asarray(ds.payload_tokens) == nxt).all()
 
 
+def test_corpus_keys_batched_equals_one_forward(tiny_ralm, monkeypatch):
+    """The batched, logit-free corpus pass (last batch padded) yields
+    exactly the hidden states of one whole-corpus forward."""
+    from repro.serve import datastore
+
+    cfg, params, corpus, _, _, _ = tiny_ralm
+    monkeypatch.setattr(datastore, "CORPUS_BATCH_TOKENS",
+                        24 * corpus.shape[1])        # 3 batches of 24 docs
+    keys, nxt = DatastoreBuilder.corpus_keys(params, cfg, corpus)
+    _, _, hidden = tf.forward(params, cfg, tokens=jnp.asarray(corpus),
+                              mode="train", return_hidden=True)
+    want = np.asarray(hidden[:, :-1].astype(jnp.float32)).reshape(
+        -1, cfg.d_model)
+    np.testing.assert_allclose(keys, want, rtol=1e-5, atol=1e-5)
+    assert (nxt == corpus[:, 1:].reshape(-1)).all()
+
+
+def test_datastore_list_cap_from_data():
+    """list_cap=None sizes the padded lists to the longest per-shard
+    list slice, rounded up to 128 rows, and every vector is indexed."""
+    rng = np.random.default_rng(1)
+    vecs = rng.normal(size=(1500, 32)).astype(np.float32)
+    ds = DatastoreBuilder(dim=32, nlist=4, m=8, list_cap=None,
+                          num_shards=2).build(vecs)
+    cap = ds.index_cfg.list_cap
+    lens = np.stack([np.asarray(s.list_len) for s in ds.shards])
+    assert cap % 128 == 0 and lens.max() <= cap < lens.max() + 128
+    assert lens.sum() == 1500
+
+
+def test_from_config_disaggregate_without_devices_raises(tiny_ralm):
+    """A disaggregated config on a host with too few devices is an
+    error, not a silent monolithic engine."""
+    from repro.serve import EngineConfig
+
+    cfg, params, _, ds, ccfg, rag = tiny_ralm
+    with pytest.raises(ValueError, match="needs lm_devices"):
+        RalmEngine.from_config(
+            EngineConfig(model=cfg, rag=rag, disaggregate=True,
+                         lm_devices=1, ret_devices=1), params, ds, ccfg)
+
+
 # ---------------------------------------------------------------------------
 # scheduler: continuous batching semantics
 # ---------------------------------------------------------------------------
