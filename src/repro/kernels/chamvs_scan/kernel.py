@@ -148,6 +148,7 @@ def fused_scan(luts: jnp.ndarray, codes: jnp.ndarray, gids: jnp.ndarray,
                                m=m, ksub=ksub, kk=kk)
     out_d, out_i = pl.pallas_call(
         kernel,
+        name="chamvs_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, tile_q, 1), lambda s, q, p: (s, p, q, 0)),

@@ -153,6 +153,7 @@ def fused_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
                                scale=D ** -0.5)
     out = pl.pallas_call(
         kernel,
+        name="decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(B // tile_b, S // blk),

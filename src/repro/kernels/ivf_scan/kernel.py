@@ -66,6 +66,7 @@ def ivf_scan(queries: jnp.ndarray, centroids: jnp.ndarray, nprobe: int,
                                nprobe=nprobe)
     out_d, out_i = pl.pallas_call(
         kernel,
+        name="ivf_scan",
         grid=(nq // tile_q, nlist // tile_c),
         in_specs=[
             pl.BlockSpec((tile_q, D), lambda qi, ci: (qi, 0)),

@@ -1,14 +1,31 @@
 """Per-request tracing: ring-buffered spans exported as Chrome trace
-events (Perfetto-loadable).
+events (Perfetto-loadable), and the same spans in the JAX profiler's
+trace whenever a profiler session is open.
+
+A span has two sinks:
+
+  * the tracer's own ring buffer (``enabled``), exported as Chrome JSON
+    on ``perf_counter`` time;
+  * the profiler's trace: while ``jax.profiler`` is tracing (checked with
+    ``TraceAnnotation.is_enabled()``, whatever ``enabled`` says), each
+    ``span()`` also opens a ``TraceAnnotation`` named ``ralm.<name>``,
+    with the span's args as its metadata. It lands on the profiler's
+    host plane, on one clock with the device's operations, so a gap in
+    the device trace can be put down to the span open across it. The
+    disabled ``NULL_TRACER`` feeds this sink too.
+
+Instants, flows and the retroactive ``complete()`` spans (queue waits,
+compiles) go to the Chrome buffer only: a profiler annotation can only
+be opened and closed as it happens.
 
 Design constraints, in priority order:
 
   1. **Zero cost when disabled.** The serving hot path (one scheduler
      wave per generated token) cannot afford allocations for telemetry
-     nobody asked for. A disabled tracer's ``span()`` returns one
+     nobody asked for. With neither sink active, ``span()`` returns one
      process-wide ``_NullSpan`` singleton — no span object, no event
      dict, no timestamp read — and the instrumentation sites build
-     their ``args`` dicts only behind an ``if tracer.enabled`` guard.
+     their ``args`` dicts only behind an ``if tracer.active`` guard.
      ``tests/test_obs.py::test_overhead_guard_disabled_tracer`` pins
      this with tracemalloc.
   2. **Thread-safe, bounded, never blocking.** Events land in a
@@ -42,8 +59,12 @@ import json
 import os
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Callable, Dict, List, Optional, Union
+
+import jax.monitoring
+from jax.profiler import TraceAnnotation
 
 __all__ = ["Tracer", "NULL_TRACER", "validate_chrome_trace"]
 
@@ -63,24 +84,42 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+#: prefix of every span's name in the profiler's trace (``bench.*`` is the
+#: benchmark harness's own)
+PROFILER_PREFIX = "ralm."
+
+#: True while a ``jax.profiler`` session is tracing
+_profiling = TraceAnnotation.is_enabled
+
 
 class _Span:
-    """A live span: times its ``with`` body, records one ``X`` event."""
-    __slots__ = ("_tracer", "name", "tid", "args", "_t0")
+    """A live span: times its ``with`` body into the Chrome buffer (one
+    ``X`` event) when the tracer is enabled, and opens a profiler
+    annotation around it when a profiler session is tracing."""
+    __slots__ = ("_tracer", "name", "tid", "args", "_t0", "_ann")
 
-    def __init__(self, tracer: "Tracer", name: str, tid: int,
-                 args: Optional[dict]):
-        self._tracer = tracer
+    def __init__(self, tracer: "Tracer", name: str, track: str,
+                 args: Optional[dict], profiling: bool):
+        self._tracer = tracer if tracer.enabled else None
         self.name = name
-        self.tid = tid
+        self.tid = tracer._tid(track) if tracer.enabled else 0
         self.args = args
+        self._ann = (TraceAnnotation(PROFILER_PREFIX + name, **(args or {}))
+                     if profiling else None)
 
     def __enter__(self) -> "_Span":
-        self._t0 = self._tracer._clock()
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._tracer is not None:
+            self._t0 = self._tracer._clock()
         return self
 
     def __exit__(self, *exc) -> bool:
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         tr = self._tracer
+        if tr is None:
+            return False
         t1 = tr._clock()
         ev = {"name": self.name, "ph": "X", "pid": tr.pid,
               "tid": self.tid, "ts": (self._t0 - tr._origin) * 1e6,
@@ -94,13 +133,15 @@ class _Span:
 class Tracer:
     """Ring-buffered trace recorder with named tracks.
 
-    ``enabled`` is the master switch: every recording method returns
-    immediately (span: the null singleton) when it is False, so a
-    deployment can keep the instrumentation compiled in and pay only an
-    attribute check per wave. Tracks are logical lanes in the viewer
-    ("wave", "retrieval", "requests", ...) mapped to stable ``tid``
-    integers, each announced once with a ``thread_name`` metadata
-    event."""
+    ``enabled`` is the switch of the Chrome buffer: every recording
+    method returns immediately when it is False, and ``span()`` returns
+    the null singleton unless a profiler session is tracing, so a
+    deployment can keep the instrumentation compiled in and pay an
+    attribute check and one ``is_enabled()`` call per span site. Tracks
+    are logical lanes in the viewer ("wave", "retrieval", "requests",
+    ...) mapped to stable ``tid`` integers, each announced once with a
+    ``thread_name`` metadata event. A tracer constructed enabled also
+    records JAX's compiles (``jit.compile``, see ``_watch_compiles``)."""
 
     def __init__(self, enabled: bool = True, capacity: int = 1 << 16,
                  clock: Callable[[], float] = time.perf_counter):
@@ -112,6 +153,14 @@ class Tracer:
         self._events: deque = deque(maxlen=capacity)
         self._tracks: Dict[str, int] = {}
         self._lock = threading.Lock()
+        if enabled:
+            _watch_compiles(self)
+
+    @property
+    def active(self) -> bool:
+        """Whether a span would reach either sink: guard a span's
+        ``args`` dict with this on hot paths."""
+        return self.enabled or _profiling()
 
     # -- track bookkeeping --------------------------------------------------
 
@@ -139,13 +188,15 @@ class Tracer:
     def span(self, name: str, track: str = "engine",
              args: Optional[dict] = None) -> Union[_Span, _NullSpan]:
         """``with tracer.span("retrieval.scan", "retrieval"): ...`` —
-        records one complete event around the body. Returns the null
-        singleton when disabled; pass ``args`` only behind an
-        ``if tracer.enabled`` guard on hot paths (the dict literal is
-        the allocation, not this call)."""
-        if not self.enabled:
+        records one complete event around the body, and a profiler
+        annotation ``ralm.<name>`` while a profiler session is tracing.
+        Returns the null singleton when neither sink is active; pass
+        ``args`` only behind an ``if tracer.active`` guard on hot paths
+        (the dict literal is the allocation, not this call)."""
+        profiling = _profiling()
+        if not (self.enabled or profiling):
             return NULL_SPAN
-        return _Span(self, name, self._tid(track), args)
+        return _Span(self, name, track, args, profiling)
 
     def instant(self, name: str, track: str = "engine",
                 args: Optional[dict] = None) -> None:
@@ -220,9 +271,44 @@ class Tracer:
             json.dump(self.export(), f)
 
 
-#: the shared disabled tracer every component defaults to — one
-#: attribute check (`tracer.enabled`) is the entire disabled-path cost
+#: the shared disabled tracer every component defaults to: its spans
+#: reach the profiler's trace while a session is tracing, and nothing else
 NULL_TRACER = Tracer(enabled=False, capacity=1)
+
+
+# ---------------------------------------------------------------------------
+# compiles: JAX's own compile events, as retroactive spans
+# ---------------------------------------------------------------------------
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_sinks: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_compile_lock = threading.Lock()
+_compile_listening = False
+
+
+def _watch_compiles(tracer: Tracer) -> None:
+    """Record every program JAX compiles (or loads from its persistent
+    cache) from now on as a ``jit.compile`` span on the tracer's
+    "kernels" track, with the function's name and the seconds it took.
+    One ``jax.monitoring`` listener serves every such tracer; it is
+    registered with the first."""
+    global _compile_listening
+    with _compile_lock:
+        _compile_sinks.add(tracer)
+        if not _compile_listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile)
+            _compile_listening = True
+
+
+def _on_compile(event: str, duration: float, fun_name: str = "?",
+                **_) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    for tr in list(_compile_sinks):
+        tr.complete("jit.compile", "kernels", tr._clock() - duration,
+                    duration, args={"fun_name": fun_name,
+                                    "seconds": duration})
 
 
 # ---------------------------------------------------------------------------

@@ -669,12 +669,13 @@ class RetrievalService:
                         t0 - oldest, args={"rows": nrows,
                                            "entries": len(pending)})
         # NOTE: with measure=False the scan/merge spans time only the
-        # async dispatch (jax returns before the kernel finishes); with
-        # measure=True the block_until_ready makes them true stage times
-        # (the fault-tolerant dispatch always blocks: deadline/hedge
-        # decisions need the scan's real latency)
+        # async dispatch (jax returns before the kernel finishes); the
+        # kernels' device time is in the profiler trace, under their
+        # names (chamvs_scan, ivf_scan). measure=True blocks per stage to
+        # make the spans stage times (the fault-tolerant dispatch always
+        # blocks: deadline/hedge decisions need the scan's real latency)
         with tr.span("retrieval.scan", "retrieval",
-                     args={"rows": nrows} if tr.enabled else None):
+                     args={"rows": nrows} if tr.active else None):
             candidates, live = self._dispatch_scan(batch)
             if measure and candidates is not None:
                 jax.block_until_ready(candidates)

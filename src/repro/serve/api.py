@@ -377,13 +377,13 @@ class AsyncRetriever:
 
     def resolve(self, ids: jnp.ndarray, kind: str = "tokens"
                 ) -> jnp.ndarray:
-        if not self.service.config.measure:
-            return _resolve_from_tables(self.payload_tokens,
-                                        self.chunk_table, ids, kind)
+        measure = self.service.config.measure
         t0 = time.perf_counter()
         with self.service.tracer.span("retrieval.gather", "retrieval"):
             out = _resolve_from_tables(self.payload_tokens,
                                        self.chunk_table, ids, kind)
-            jax.block_until_ready(out)
-        self.service.stats.gather.add(time.perf_counter() - t0)
+            if measure:
+                jax.block_until_ready(out)
+        if measure:
+            self.service.stats.gather.add(time.perf_counter() - t0)
         return out

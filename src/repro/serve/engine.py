@@ -663,17 +663,18 @@ class RalmEngine:
                             args=args)
             if request.trace_id is not None:
                 tr.flow_start(request.trace_id)
-        with tr.span("sched.admit", "requests",
-                     args={"request_id": request.request_id,
-                           "rows": B, "prompt_len": T0}
-                     if tr.enabled else None):
+        span_args = {"request_id": request.request_id, "rows": B,
+                     "prompt_len": T0} if tr.active else None
+        with tr.span("sched.admit", "requests", args=span_args):
             if self.wave:
                 pool = self._ensure_pool(B, T0 + request.steps)
                 slots = pool.alloc(B)
-                caches, enc_states, logits0, hidden0 = \
-                    self.backend.prefill(self.rag, request.prompt,
-                                         pool.max_seq)
-                pool.write_prefill(slots, caches)
+                with tr.span("prefill", "requests", args=span_args):
+                    caches, enc_states, logits0, hidden0 = \
+                        self.backend.prefill(self.rag, request.prompt,
+                                             pool.max_seq)
+                with tr.span("prefill.scatter", "requests"):
+                    pool.write_prefill(slots, caches)
                 if enc_states is not None:
                     pool.write_enc(slots, enc_states)
                 return SequenceState(
@@ -682,8 +683,9 @@ class RalmEngine:
                     t0=T0, logits0=logits0, hidden0=hidden0,
                     rng=request.rng, slots=slots)
             max_seq = self.max_seq or (T0 + request.steps)
-            caches, enc_states, logits0, hidden0 = self.backend.prefill(
-                self.rag, request.prompt, max_seq)
+            with tr.span("prefill", "requests", args=span_args):
+                caches, enc_states, logits0, hidden0 = self.backend.prefill(
+                    self.rag, request.prompt, max_seq)
             return SequenceState(
                 request=request, caches=caches, enc_states=enc_states,
                 out=[request.prompt], cur=request.prompt[:, -1:], t0=T0,
@@ -809,7 +811,7 @@ class RalmEngine:
         tr = self.tracer
         with tr.span("wave.decode", "wave",
                      args={"rows": len(wave), "bucket": len(slots),
-                           "kv_len": kv_len} if tr.enabled else None):
+                           "kv_len": kv_len} if tr.active else None):
             logits, pool.caches, hidden = self.backend.decode_wave(
                 pool.caches, tokens, jnp.asarray(slots),
                 jnp.asarray(positions), enc_states=pool.gather_enc(slots),
@@ -870,7 +872,31 @@ class RalmEngine:
         over all due rows, one RETRO re-encode over all due chunks, one
         greedy argmax over every greedy row. Per-request ``rng`` sampling
         stays per-sequence (each request owns an independent key chain,
-        so batching it would change the sampled tokens)."""
+        so batching it would change the sampled tokens). Its three
+        phases are the spans ``wave.mix``, ``wave.sample`` and
+        ``wave.stream`` (the emit loop, with the wave's host sync)."""
+        tr = self.tracer
+        with tr.span("wave.mix", "wave"):
+            rows, spec_new = self._mix_wave(seqs, decoded, searches)
+        with tr.span("wave.sample", "wave"):
+            nxt = self._sample_wave(seqs, rows)
+        with tr.span("wave.stream", "wave"):
+            for seq, tok in zip(seqs, nxt):
+                self._emit(seq, tok)
+            # register the wave's speculation points AFTER the emits so
+            # each captures the token it produced and the pre-emit out
+            # length (eligibility guarantees these rows are greedy, so
+            # `seq.cur` now holds the token the stale mix argmax'd)
+            for seq, issue, logits in spec_new:
+                seq.spec_points.append(SpecPoint(
+                    step=seq.step - 1, handle=issue.handle, logits=logits,
+                    emitted=seq.cur, out_len=len(seq.out) - 1))
+
+    def _mix_wave(self, seqs: List[SequenceState], decoded: List,
+                  searches: List) -> Tuple[List[jnp.ndarray], List]:
+        """The wave's search results into its rows of logits: returns
+        each sequence's distribution to sample from, and the rows that
+        mixed speculated (stale) neighbours."""
         rag = self.rag
         rows: List[jnp.ndarray] = []
         knn = []                # (row_idx, logits, dists, ids)
@@ -940,6 +966,14 @@ class RalmEngine:
                 B = seq.cur.shape[0]
                 self.pool.write_enc(seq.slots, enc[off:off + B])
                 off += B
+        return rows, spec_new
+
+    @staticmethod
+    def _sample_wave(seqs: List[SequenceState],
+                     rows: List[jnp.ndarray]) -> List[jnp.ndarray]:
+        """Each sequence's next tokens: one argmax over every greedy
+        row, and a categorical draw per sampling sequence."""
+        nxt: List = [None] * len(seqs)
         greedy = [i for i, seq in enumerate(seqs)
                   if seq.request.greedy or seq.rng is None]
         if greedy:
@@ -949,22 +983,14 @@ class RalmEngine:
             off = 0
             for i in greedy:
                 B = rows[i].shape[0]
-                self._emit(seqs[i], nxt_cat[off:off + B])
+                nxt[i] = nxt_cat[off:off + B]
                 off += B
         for i, seq in enumerate(seqs):
             if seq.request.greedy or seq.rng is None:
                 continue
             seq.rng, k = jax.random.split(seq.rng)
-            self._emit(seq, jax.random.categorical(
-                k, rows[i]).astype(jnp.int32))
-        # register the wave's speculation points AFTER the emits so each
-        # captures the token it produced and the pre-emit out length
-        # (eligibility guarantees these rows are greedy, so `seq.cur`
-        # now holds the token the stale mix argmax'd)
-        for seq, issue, logits in spec_new:
-            seq.spec_points.append(SpecPoint(
-                step=seq.step - 1, handle=issue.handle, logits=logits,
-                emitted=seq.cur, out_len=len(seq.out) - 1))
+            nxt[i] = jax.random.categorical(k, rows[i]).astype(jnp.int32)
+        return nxt
 
     def _emit(self, seq: SequenceState, nxt: jnp.ndarray) -> None:
         seq.cur = nxt[:, None]
@@ -1071,7 +1097,7 @@ class RalmEngine:
         rag = self.rag
         with tr.span("spec.verify", "wave",
                      args={"points": len(pts), "force": force}
-                     if tr.enabled else None):
+                     if tr.active else None):
             t0 = time.perf_counter()
             res = [p.handle.result() for _, _, p in pts]
             # spec_wait times ONLY the forcing of the in-flight search
@@ -1169,7 +1195,7 @@ class RalmEngine:
         with tr.span("spec.rollback", "wave",
                      args={"step": point.step, "depth":
                            cur_step - point.step}
-                     if tr.enabled else None):
+                     if tr.active else None):
             # later points' queries/logits came from the timeline being
             # discarded — drop them unverified
             for p in seq.spec_points:

@@ -225,19 +225,8 @@ class KVCachePool:
         nb_full = self.max_seq // self.seq_block
         self.stats.blocks_total += nb_full
         self.stats.blocks_skipped += nb_full - kv_len // self.seq_block
-        key = (bucket, kv_len, self.capacity, self.max_seq)
-        if key not in self.stats.compiled:
-            self.stats.compiled.add(key)
-            # a new graph key means jit will trace+compile a fresh
-            # decode_wave variant on this step — the recompile stall is
-            # worth a mark in the trace
-            if self.tracer.enabled:
-                self.tracer.instant(
-                    "jit.decode_compile", "kernels",
-                    args={"bucket": bucket, "kv_len": kv_len,
-                          "capacity": self.capacity,
-                          "max_seq": self.max_seq,
-                          "graphs": len(self.stats.compiled)})
+        self.stats.compiled.add((bucket, kv_len, self.capacity,
+                                 self.max_seq))
         return kv_len
 
     def bucket(self, n: int) -> int:

@@ -208,7 +208,7 @@ class RalmScheduler:
         t_wave = time.perf_counter()
         with tr.span("sched.step", "wave",
                      args={"active": len(self.active)}
-                     if tr.enabled else None):
+                     if tr.active else None):
             decoded = self.engine.dispatch_wave(self.active)
             if self.engine.speculate_k > 0:
                 # speculation harvest: verify points whose real search
@@ -225,10 +225,14 @@ class RalmScheduler:
                 self.engine.finish_wave(self.active, decoded, searches)
         if self.active:
             self._record_wave(time.perf_counter() - t_wave)
+        done = [seq for seq in self.active if seq.done]
+        if not done:
+            return []
+        self.active = [seq for seq in self.active if not seq.done]
         finished: List[RalmResponse] = []
-        still_active = []
-        for seq in self.active:
-            if seq.done:
+        with tr.span("request.finish", "wave",
+                     args={"requests": len(done)} if tr.active else None):
+            for seq in done:
                 if seq.spec_points:
                     # settle outstanding speculation before the response
                     # leaves the system (forced verify; discard when
@@ -236,9 +240,6 @@ class RalmScheduler:
                     self.engine.spec_finalize(seq)
                 self.engine.release(seq)   # slots free for queued work
                 finished.append(self._response(seq))
-            else:
-                still_active.append(seq)
-        self.active = still_active
         return finished
 
     def _record_wave(self, duration_s: float) -> None:

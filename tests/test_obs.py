@@ -44,7 +44,7 @@ from repro.obs import (DEFAULT_BUCKETS, Histogram, MetricsRegistry,
 from repro.obs.trace import NULL_SPAN, NULL_TRACER
 from repro.retrieval.stats import RetrievalStats, StageStat
 from repro.serve import (DatastoreBuilder, RagConfig, RalmEngine,
-                         RalmRequest)
+                         RalmRequest, ServiceConfig)
 from repro.serve.gateway import Gateway, GatewayConfig
 
 # ---------------------------------------------------------------------------
@@ -421,18 +421,26 @@ def test_trace_id_propagates_through_wave(tiny_ralm):
     rid = eng.submit(req)
     assert req.trace_id == rid                   # defaulted at submit
     eng.run()
+    # a program new to this process: JAX compiles it whatever ran before
+    jax.jit(lambda x: x * 5 - 1)(jnp.arange(3)).block_until_ready()
 
     doc = eng.tracer.export()
     assert validate_chrome_trace(doc) == []
     evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
-    for expected in ("queue.wait", "sched.admit", "sched.step",
+    for expected in ("queue.wait", "sched.admit", "prefill",
+                     "prefill.scatter", "sched.step",
                      "wave.decode", "wave.search", "wave.finish",
+                     "wave.mix", "wave.sample", "wave.stream",
+                     "request.finish",
                      "retrieval.queue_wait", "retrieval.scan",
                      "retrieval.merge", "retrieval.gather",
                      "kvpool.alloc", "kvpool.release",
-                     "jit.decode_compile"):
+                     "jit.compile"):
         assert expected in names, f"span {expected!r} missing"
+    compiles = [e for e in evs if e["name"] == "jit.compile"]
+    assert all(isinstance(e["args"]["fun_name"], str)
+               and e["args"]["seconds"] >= 0 for e in compiles)
     tracks = {e["tid"]: e["args"]["name"] for e in evs if e["ph"] == "M"}
     by_name = {e["name"]: e for e in evs if e["ph"] in ("X", "i")}
     assert tracks[by_name["wave.decode"]["tid"]] == "wave"
@@ -465,6 +473,123 @@ def test_disabled_tracer_records_nothing_on_wave(tiny_ralm):
     assert off.tracer.events() == []
     assert len(on.tracer.events()) > 0
     np.testing.assert_array_equal(out_on, out_off)
+
+
+def test_gather_span_in_the_serving_configuration(tiny_ralm):
+    """``measure=False`` (how the engine serves): the payload gather is
+    still a span, around its dispatch, and no stage time is recorded,
+    since that would need a host sync."""
+    cfg, params, corpus, ds, ccfg, rag = tiny_ralm
+    retriever = ds.async_retriever(ccfg,
+                                   service_cfg=ServiceConfig(measure=False))
+    eng = RalmEngine.monolithic(params, cfg, rag, retriever, max_seq=64,
+                                kv_slots=8, attn_seq_block=64)
+    eng.set_tracer(Tracer(enabled=True))
+    eng.generate(jnp.asarray(corpus[:2, :8]), steps=2)
+    names = {e["name"] for e in eng.tracer.events()}
+    assert "retrieval.gather" in names
+    assert retriever.service.stats.gather.count == 0
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink: spans in jax.profiler's trace, on the device's clock
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, body):
+    """Run ``body()`` inside a profiler session; returns the host plane's
+    events as {name: [(start ns, end ns), ...]}. The session is stopped
+    whatever happens, so no later test sees an active profiler."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(tmp_path.glob("**/*.xplane.pb"))[-1]
+    out = {}
+    for plane in ProfileData.from_file(str(path)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                out.setdefault(e.name, []).append(
+                    (e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def _inside(inner, outers):
+    a, b = inner
+    return any(lo <= a and b <= hi for lo, hi in outers)
+
+
+def test_profiler_sink_nests_on_one_clock(tmp_path):
+    """Inside a profiler session every tracer's spans land in the trace as
+    ``ralm.<name>``, args as metadata (the name is left as it is),
+    nested as opened and on the clock of an enclosing annotation; an
+    enabled tracer fills its Chrome buffer as well. Outside a session
+    the disabled tracer is null again."""
+    chrome = Tracer(enabled=True)
+    assert not NULL_TRACER.active
+    with NULL_TRACER.span("before") as sp:
+        assert sp is NULL_SPAN
+
+    def body():
+        assert NULL_TRACER.active
+        with jax.profiler.TraceAnnotation("outer"):
+            with NULL_TRACER.span("a.outer", "wave", args={"rows": 3}):
+                with chrome.span("a.inner", "wave"):
+                    jnp.ones(4).block_until_ready()
+    ev = _profiled(tmp_path, body)
+    assert NULL_TRACER.span("after") is NULL_SPAN
+    assert "ralm.before" not in ev and "ralm.after" not in ev
+    (outer,), (a,), (b,) = ev["outer"], ev["ralm.a.outer"], ev["ralm.a.inner"]
+    assert _inside(a, [outer]) and _inside(b, [a])
+    assert [e["name"] for e in chrome.events()
+            if e["name"].startswith("a.")] == ["a.inner"]
+    assert NULL_TRACER.events() == []
+
+
+def test_profiled_wave_names_its_phases(tiny_ralm, tmp_path):
+    """A wave of the tiny model under a profiler session, tracer off:
+    prefill, the wave's phases and the completion work are named in the
+    trace, each nested where it runs, and no name is the harness's."""
+    corpus = tiny_ralm[2]
+    eng = _traced_engine(tiny_ralm, enabled=False)
+    prompt = jnp.asarray(corpus[:2, :8])
+    eng.generate(prompt, steps=3)          # compile outside the session
+    ev = _profiled(tmp_path, lambda: eng.generate(prompt, steps=3))
+    assert eng.tracer.events() == []
+    steps, admits = ev["ralm.sched.step"], ev["ralm.sched.admit"]
+    assert len(steps) == 3
+    for name in ("ralm.wave.decode", "ralm.wave.search", "ralm.wave.finish",
+                 "ralm.retrieval.scan"):
+        assert all(_inside(s, steps) for s in ev[name]), name
+    for name in ("ralm.wave.mix", "ralm.wave.sample", "ralm.wave.stream"):
+        assert len(ev[name]) == 3
+        assert all(_inside(s, ev["ralm.wave.finish"]) for s in ev[name])
+    assert all(_inside(s, ev["ralm.wave.mix"])
+               for s in ev["ralm.retrieval.gather"])
+    for name in ("ralm.prefill", "ralm.prefill.scatter"):
+        assert len(ev[name]) == 1 and _inside(ev[name][0], admits)
+    assert len(ev["ralm.request.finish"]) == 1
+    assert not any(n.startswith("bench.") for n in ev)
+
+
+def test_compile_listener_is_registered_once():
+    """Every enabled tracer gets each of JAX's compiles once, as a
+    ``jit.compile`` span with the function's name and its seconds,
+    however many tracers listen."""
+    a, b = Tracer(enabled=True), Tracer(enabled=True)
+
+    def times_seven(x):
+        return x * 7 + 2
+    jax.jit(times_seven)(jnp.arange(4)).block_until_ready()
+    for tr in (a, b):
+        evs = [e for e in tr.events() if e["name"] == "jit.compile"
+               and "times_seven" in e["args"]["fun_name"]]
+        assert len(evs) == 1 and evs[0]["ph"] == "X"
+        assert evs[0]["args"]["seconds"] >= 0
 
 
 # ---------------------------------------------------------------------------
