@@ -514,6 +514,27 @@ def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray
     return h
 
 
+def prefill(params: Params, cfg: ModelConfig, caches: Params,
+            tokens: jnp.ndarray, length, enc_states=None):
+    """Serving prefill. tokens [B,T] hold the prompt at positions
+    < ``length`` (an int32 scalar, may be traced) and anything after it:
+    causal attention keeps the tail out of every prompt row, and its K/V
+    land at cache positions >= ``length``. Returns (logits [B,V], caches,
+    hidden [B,d]) at position ``length - 1``; no other position is
+    unembedded."""
+    B, T = tokens.shape
+    pos = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
+    if cfg.rope_mode == "mrope":
+        pos = jnp.broadcast_to(pos[None], (3, B, T))
+    h = constrain(embed_tokens(params, tokens), "dp", None, None)
+    h, caches = apply_stack(cfg, params["classes"], h, pos, "prefill",
+                            caches, enc_states)
+    h = constrain(h, "dp", None, None)
+    last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=1,
+                                        keepdims=False)
+    return unembed(params, cfg, last), caches, last
+
+
 def decode_step(params: Params, cfg: ModelConfig, caches: Params,
                 token: jnp.ndarray, position: jnp.ndarray,
                 enc_states: Optional[jnp.ndarray] = None,

@@ -26,8 +26,9 @@ _STAGES = ("queue_wait", "scan", "merge", "gather")
 
 def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
     """Register collectors for everything an ``RalmEngine`` owns: KV
-    pool, retrieval service, kernel-registry fallbacks. Idempotent
-    metric creation; call once per (registry, engine) pair."""
+    pool, admission prefill, retrieval service, kernel-registry
+    fallbacks. Idempotent metric creation; call once per (registry,
+    engine) pair."""
     kv_slots = registry.gauge(
         "ralm_kv_slots", "KV-pool slot rows by state")
     kv_allocs = registry.counter(
@@ -44,6 +45,15 @@ def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
     kv_skip = registry.gauge(
         "ralm_kv_attn_skip_fraction",
         "fraction of pool seq blocks cropped by length-aware attention")
+    prefill_calls = registry.counter(
+        "ralm_prefill_calls_total", "admission prefills run")
+    prefill_programs = registry.gauge(
+        "ralm_prefill_programs",
+        "distinct prefill programs (bucket, rows, max_seq) built")
+    prefill_tokens = registry.counter(
+        "ralm_prefill_tokens_total",
+        "prefill positions by kind (prompt, or tail padding up to the "
+        "prompt-length bucket)")
     fallbacks = registry.counter(
         "ralm_kernel_fallbacks_total",
         "pallas->ref kernel routing decisions, by op")
@@ -117,6 +127,11 @@ def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
             kv_waves.set_total(ps.waves)
             kv_compiles.set(ps.decode_compiles)
             kv_skip.set(ps.skip_fraction())
+        pf = engine.prefill_stats
+        prefill_calls.set_total(pf.calls)
+        prefill_programs.set(len(pf.programs))
+        prefill_tokens.set_total(pf.prompt_tokens, labels={"kind": "prompt"})
+        prefill_tokens.set_total(pf.pad_tokens, labels={"kind": "pad"})
         from repro.kernels import registry as kreg
         for op, n in kreg.fallback_counts().items():
             fallbacks.set_total(n, labels={"op": op})

@@ -21,7 +21,8 @@ from repro.serve.api import (AsyncRetriever, DistributedRetriever,
                              RalmResponse, Retriever)
 from repro.serve.datastore import Datastore, DatastoreBuilder
 from repro.serve.engine import (DisaggregatedBackend, MonolithicBackend,
-                                PoolTimes, RalmEngine, SequenceState)
+                                PoolTimes, PrefillStats, RalmEngine,
+                                SequenceState)
 from repro.serve.gateway import (AdmissionController, DegradeConfig,
                                  DegradePolicy, Gateway, GatewayConfig,
                                  TenantQuota)
@@ -33,7 +34,8 @@ __all__ = [
     "DatastoreBuilder", "DegradeConfig", "DegradePolicy",
     "DisaggregatedBackend", "DistributedRetriever", "EngineConfig",
     "Gateway", "GatewayConfig", "KVCachePool", "LocalRetriever",
-    "MonolithicBackend", "PoolStats", "PoolTimes", "RagConfig",
+    "MonolithicBackend", "PoolStats", "PoolTimes", "PrefillStats",
+    "RagConfig",
     "RalmEngine", "RalmRequest", "RalmResponse", "RalmScheduler",
     "RetrievalService", "Retriever", "SearchHandle", "SequenceState",
     "ServiceConfig", "TenantQuota",

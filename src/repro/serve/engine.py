@@ -81,27 +81,71 @@ class PoolTimes:
 # decode backends
 # ---------------------------------------------------------------------------
 
-def _prefill(params, cfg: ModelConfig, rag: RagConfig,
-             prompt: jnp.ndarray, max_seq: int):
-    """Consume the prompt. Returns (caches, enc_states, last_logits [B,V],
-    last_hidden [B,d]) — the hidden state at the last prompt position is
-    the step-0 retrieval query."""
-    B, T0 = prompt.shape
+PREFILL_MIN_BUCKET = 16
+
+
+@dataclasses.dataclass
+class PrefillStats:
+    """Admission-prefill accounting (/statsz, /metricsz, tests)."""
+    calls: int = 0               # prefills run
+    prompt_tokens: int = 0       # real prompt tokens consumed (rows x len)
+    pad_tokens: int = 0          # tail padding up to each call's bucket
+    programs: set = dataclasses.field(default_factory=set)
+    #                            # distinct (bucket, rows, max_seq) keys:
+    #                            # one compiled prefill program each
+
+
+def pads_unread(cfg: ModelConfig) -> bool:
+    """Whether a prompt padded at its tail prefills its real rows and
+    cache entries as the exact prompt does, with no pad entry ever read.
+    True when every cache class is a full-length KV cache (dense blocks,
+    no sliding-window ring): causal attention keeps the pads out of
+    every real row, and their K/V land at positions >= the prompt
+    length, which decode overwrites before it reads them. A ring would
+    wrap pad K/V over real positions, a recurrent state (``rwkv6``,
+    ``hybrid``) would carry the pads forward, and MoE capacity would let
+    pads displace real tokens."""
+    return cfg.block == "dense" and not (
+        cfg.window > 0 and "local" in cfg.pattern_classes())
+
+
+def prefill_bucket(cfg: ModelConfig, prompt_len: int, max_seq: int) -> int:
+    """The length the prefill program runs at: the prompt's power-of-two
+    bucket (at least ``PREFILL_MIN_BUCKET``, at most ``max_seq``) where
+    ``pads_unread``, else the exact length."""
+    if not pads_unread(cfg) or prompt_len >= max_seq:
+        return prompt_len
+    return min(max(next_pow2(prompt_len), PREFILL_MIN_BUCKET), max_seq)
+
+
+@functools.partial(jax.jit, static_argnums=1,
+                   static_argnames=("max_seq", "enc_len"))
+def _jit_prefill(params, cfg: ModelConfig, prompt, length, *, max_seq: int,
+                 enc_len: int):
+    """Consume a prompt [B, T] whose real tokens fill positions
+    < ``length`` (a traced scalar, so every length of a bucket shares one
+    program). Returns (caches, enc_states, last_logits [B,V],
+    last_hidden [B,d]) — the hidden state at the last real position is
+    the step-0 retrieval query. ``enc_len`` is the width of the neutral
+    encoder input (0: no encoder). One shared jit cache for all
+    backends/engines, keyed on (cfg, bucket, rows, max_seq, enc_len)."""
+    B = prompt.shape[0]
     caches = tf.init_cache(cfg, B, max_seq=max_seq, enc_len=0)
     enc_states = None
-    if cfg.arch == "encdec":
-        enc_len = rag.k * rag.chunk_len if rag.mode == "retro" else 0
-        neutral = jnp.zeros((B, max(enc_len, 8)), jnp.int32)
+    if enc_len:
+        neutral = jnp.zeros((B, enc_len), jnp.int32)
         enc_states = tf.encode(params, cfg, tf.embed_tokens(params, neutral))
-    pos = jnp.broadcast_to(jnp.arange(T0)[None], (B, T0))
-    if cfg.rope_mode == "mrope":
-        pos = jnp.broadcast_to(pos[None], (3, B, T0))
-    logits, caches, hidden = tf.forward(
-        params, cfg, tokens=prompt, positions=pos, mode="prefill",
-        caches=caches, enc_states=enc_states, return_hidden=True)
-    last_logits = logits if logits.ndim == 2 else logits[:, -1]
-    last_hidden = hidden if hidden.ndim == 2 else hidden[:, -1]
-    return caches, enc_states, last_logits, last_hidden
+    logits, caches, hidden = tf.prefill(params, cfg, caches, prompt, length,
+                                        enc_states=enc_states)
+    return caches, enc_states, logits, hidden
+
+
+def _neutral_enc_len(cfg: ModelConfig, rag: RagConfig) -> int:
+    """Width of the neutral encoder input an encdec prefill attends to
+    (RETRO: k retrieved chunks; at least 8 tokens); 0 for a decoder."""
+    if cfg.arch != "encdec":
+        return 0
+    return max(rag.k * rag.chunk_len if rag.mode == "retro" else 0, 8)
 
 
 @functools.partial(jax.jit, static_argnums=1,
@@ -143,8 +187,12 @@ class MonolithicBackend:
         self.params, self.cfg = params, cfg
         self.decode_dispatches = 0      # LM dispatch counter (tests/bench)
 
-    def prefill(self, rag: RagConfig, prompt: jnp.ndarray, max_seq: int):
-        return _prefill(self.params, self.cfg, rag, prompt, max_seq)
+    def prefill(self, rag: RagConfig, prompt, length: int, max_seq: int):
+        """Prefill ``prompt`` [B, T] whose first ``length`` positions are
+        the real prompt (see ``RalmEngine._prefill``)."""
+        return _jit_prefill(self.params, self.cfg, prompt, np.int32(length),
+                            max_seq=max_seq,
+                            enc_len=_neutral_enc_len(self.cfg, rag))
 
     def decode(self, caches, token, position, enc_states=None,
                attn_spec=None):
@@ -194,9 +242,11 @@ class DisaggregatedBackend:
         self.ret_mesh = make_mesh_for(
             devs[lm_devices:lm_devices + ret_devices], data=ret_devices)
 
-    def prefill(self, rag: RagConfig, prompt: jnp.ndarray, max_seq: int):
+    def prefill(self, rag: RagConfig, prompt, length: int, max_seq: int):
         with use_mesh(self.lm_mesh):
-            return _prefill(self.params, self.cfg, rag, prompt, max_seq)
+            return _jit_prefill(self.params, self.cfg, prompt,
+                                np.int32(length), max_seq=max_seq,
+                                enc_len=_neutral_enc_len(self.cfg, rag))
 
     def decode(self, caches, token, position, enc_states=None,
                attn_spec=None):
@@ -389,6 +439,7 @@ class RalmEngine:
             self._spec_depth = 1
         self._local_spec_stats = None    # fallback when no service
         self.pool: Optional[KVCachePool] = None   # built at first admission
+        self.prefill_stats = PrefillStats()
         self.times: Optional[PoolTimes] = getattr(backend, "times", None)
         self.scheduler = RalmScheduler(self, max_active=max_active)
         self._unclaimed: List[RalmResponse] = []
@@ -669,27 +720,47 @@ class RalmEngine:
             if self.wave:
                 pool = self._ensure_pool(B, T0 + request.steps)
                 slots = pool.alloc(B)
-                with tr.span("prefill", "requests", args=span_args):
-                    caches, enc_states, logits0, hidden0 = \
-                        self.backend.prefill(self.rag, request.prompt,
-                                             pool.max_seq)
+                (caches, enc_states, logits0, hidden0), cur = \
+                    self._prefill(request, pool.max_seq, span_args)
                 with tr.span("prefill.scatter", "requests"):
                     pool.write_prefill(slots, caches)
                 if enc_states is not None:
                     pool.write_enc(slots, enc_states)
                 return SequenceState(
                     request=request, caches=None, enc_states=None,
-                    out=[request.prompt], cur=request.prompt[:, -1:],
-                    t0=T0, logits0=logits0, hidden0=hidden0,
-                    rng=request.rng, slots=slots)
+                    out=[request.prompt], cur=cur, t0=T0, logits0=logits0,
+                    hidden0=hidden0, rng=request.rng, slots=slots)
             max_seq = self.max_seq or (T0 + request.steps)
-            with tr.span("prefill", "requests", args=span_args):
-                caches, enc_states, logits0, hidden0 = self.backend.prefill(
-                    self.rag, request.prompt, max_seq)
+            (caches, enc_states, logits0, hidden0), cur = \
+                self._prefill(request, max_seq, span_args)
             return SequenceState(
                 request=request, caches=caches, enc_states=enc_states,
-                out=[request.prompt], cur=request.prompt[:, -1:], t0=T0,
-                logits0=logits0, hidden0=hidden0, rng=request.rng)
+                out=[request.prompt], cur=cur, t0=T0, logits0=logits0,
+                hidden0=hidden0, rng=request.rng)
+
+    def _prefill(self, request: RalmRequest, max_seq: int,
+                 span_args: Optional[dict]):
+        """Run the request's prefill at its ``prefill_bucket`` length: the
+        prompt is padded at its tail with token 0 on the host, so every
+        length of a bucket runs the same compiled program. Returns the
+        backend's (caches, enc_states, logits0, hidden0) and the last
+        prompt token [B, 1], also taken on the host (no program per exact
+        length)."""
+        prompt = np.asarray(request.prompt)
+        B, T0 = prompt.shape
+        bucket = prefill_bucket(self.cfg, T0, max_seq)
+        st = self.prefill_stats
+        st.calls += 1
+        st.prompt_tokens += B * T0
+        st.pad_tokens += B * (bucket - T0)
+        st.programs.add((bucket, B, max_seq))
+        if span_args is not None:
+            span_args = dict(span_args, bucket=bucket)
+        with self.tracer.span("prefill", "requests", args=span_args):
+            out = self.backend.prefill(
+                self.rag, np.pad(prompt, ((0, 0), (0, bucket - T0))), T0,
+                max_seq)
+        return out, jnp.asarray(prompt[:, -1:])
 
     def dispatch_decode(self, seq: SequenceState
                         ) -> Tuple[jnp.ndarray, jnp.ndarray]:

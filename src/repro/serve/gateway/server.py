@@ -13,8 +13,8 @@ no framework deps), OpenAI-compatible:
     GET  /v1/models        the one deployed model
     GET  /healthz          liveness (incl. the step thread)
     GET  /statsz           scheduler queue depths/ages, admission and
-                           degrade counters, pool + retrieval + kernel
-                           stats, plus a snapshot of the metrics
+                           degrade counters, pool + prefill + retrieval
+                           + kernel stats, plus a snapshot of the metrics
                            registry (the JSON view of /metricsz)
     GET  /metricsz         Prometheus text exposition of the same
                            registry (repro.obs: TTFT/TPOT/queue-wait
@@ -579,6 +579,10 @@ class Gateway:
                                   skip_fraction=ps.skip_fraction(),
                                   blocks_total=ps.blocks_total,
                                   blocks_skipped=ps.blocks_skipped)
+        pf = eng.prefill_stats
+        out["prefill"] = dict(calls=pf.calls, programs=len(pf.programs),
+                              prompt_tokens=pf.prompt_tokens,
+                              pad_tokens=pf.pad_tokens)
         # degraded kernel routing must be visible in production, not
         # just under pytest: per-op pallas->ref fallback decisions
         out["kernels"] = dict(

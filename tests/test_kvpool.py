@@ -158,6 +158,21 @@ def test_wave_parity_mixed_prompt_lengths(tiny_ralm):
         assert (by_id[rid] == oracle_tokens(tiny_ralm, p, s)).all()
 
 
+def test_wave_parity_prompts_straddle_prefill_bucket(tiny_ralm):
+    """Prompts of 16 and 17 tokens prefill in different buckets (16 and
+    32, the latter padded at its tail) and share the pool; greedy tokens
+    still match the per-sequence runs."""
+    cfg, params, corpus, ds, ccfg, rag = tiny_ralm
+    specs = [(corpus[:2, :16], 5), (corpus[2:3, :17], 5)]
+    eng = RalmEngine.monolithic(params, cfg, rag, ds.retriever(ccfg))
+    rids = [eng.submit(RalmRequest(prompt=jnp.asarray(p), steps=s))
+            for p, s in specs]
+    by_id = {r.request_id: r.tokens for r in eng.run()}
+    assert eng.prefill_stats.pad_tokens == 32 - 17
+    for rid, (p, s) in zip(rids, specs):
+        assert (by_id[rid] == oracle_tokens(tiny_ralm, p, s)).all()
+
+
 def test_wave_parity_mid_run_admission_and_early_finishers(tiny_ralm):
     """A request admitted mid-run joins the wave; a short request
     finishes early, frees its slots, and a queued request reuses them —

@@ -449,6 +449,9 @@ def test_trace_id_propagates_through_wave(tiny_ralm):
     # the request's identity rides the spans...
     admit = by_name["sched.admit"]
     assert admit["args"]["request_id"] == rid
+    # the 8-token prompt prefills in the 16-position bucket
+    assert by_name["prefill"]["args"]["prompt_len"] == 8
+    assert by_name["prefill"]["args"]["bucket"] == 16
     assert by_name["queue.wait"]["args"]["trace_id"] == rid
     # ...and the flow arrow is paired on exactly that id
     flows = [e for e in evs if e.get("cat") == "flow"]
@@ -662,6 +665,11 @@ def test_gateway_metricsz_exposition(obs_gw):
     assert samples['ralm_admission_total{outcome="admitted"}'] >= 1
     assert samples['ralm_kv_slots{state="used"}'] == 0   # idle now
     assert "ralm_retrieval_queries_total" in samples
+    # one 8-token prompt so far, padded to its 16-position bucket
+    assert samples["ralm_prefill_calls_total"] >= 1
+    assert samples["ralm_prefill_programs"] >= 1
+    assert samples['ralm_prefill_tokens_total{kind="prompt"}'] >= 8
+    assert samples['ralm_prefill_tokens_total{kind="pad"}'] >= 8
     assert samples['ralm_retrieval_stage_seconds'
                    '{stage="scan",stat="p99"}'] >= 0
 
@@ -696,6 +704,9 @@ def test_gateway_statsz_satellite_fields(obs_gw):
                 "blocks_skipped"):
         assert key in kv, key
     assert kv["decode_compiles"] >= 1
+    pf = stats["prefill"]
+    assert pf["calls"] >= 1 and pf["programs"] >= 1
+    assert pf["prompt_tokens"] >= 8 and pf["pad_tokens"] >= 8
     kern = stats["kernels"]
     assert isinstance(kern["fallbacks"], dict)
     assert kern["fallback_total"] == sum(kern["fallbacks"].values())
