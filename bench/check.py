@@ -1,6 +1,7 @@
 """Whether what the timed path served is correct: the served requests
-against the plain reference (``reference.py``), once the window has
-closed.
+against the plain reference (the configuration's LM module under
+``bench/lm/``, and ``reference.py``'s search and mixture), once the
+window has closed.
 
 What the timed path produced, per served token of a sample of finished
 requests (drawn from the seed, with the longest among them):
@@ -165,13 +166,14 @@ def _positions(sample: Sequence, max_seq: int):
     return toks, np.asarray(where, np.int32), keys
 
 
-def hidden_at(params, m: ref.Model, toks: np.ndarray, where: np.ndarray,
+def hidden_at(lm, params, m, toks: np.ndarray, where: np.ndarray,
               prec: str) -> jnp.ndarray:
-    """Hidden states [P, d] at ``where`` of the model over ``toks``."""
+    """Hidden states [P, d] at ``where`` of the LM module ``lm``'s model
+    ``m`` over ``toks``."""
     out = []
     for s in range(0, toks.shape[0], REF_ROWS):
-        h = ref.forward_hidden(params, jnp.asarray(toks[s:s + REF_ROWS]), m,
-                               prec)
+        h = lm.forward_hidden(params, jnp.asarray(toks[s:s + REF_ROWS]), m,
+                              prec)
         sel = where[(where[:, 0] >= s) & (where[:, 0] < s + REF_ROWS)]
         out.append(h[sel[:, 0] - s, sel[:, 1]])
     return jnp.concatenate(out)
@@ -223,15 +225,15 @@ def _dists(tables, q, ids, prec):
     return d
 
 
-def _mix(tables, lm, d, i, rag):
+def _mix(tables, logits, d, i, rag):
     (mx,) = _blocks(lambda a, b, c: (ref.mixture(
         a, b, c, tables.payload, rag["lam"], rag["temperature"]),),
-        (lm, d, i), 64)
+        (logits, d, i), 64)
     return mx
 
 
-def _logits(params, h, m, prec):
-    (lg,) = _blocks(lambda x: (ref.lm_logits(params, x, m, prec),), (h,), 64)
+def _logits(lm, params, h, m, prec):
+    (lg,) = _blocks(lambda x: (lm.lm_logits(params, x, m, prec),), (h,), 64)
     return lg
 
 
@@ -259,16 +261,19 @@ def _gap(mix, tok):
 
 class Reference:
     """The reference's view of one run's sample: hidden states, logits,
-    top-K and mixture at every served position."""
+    top-K and mixture at every served position. ``lm`` is the
+    configuration's LM module and ``m`` its ``Model``."""
 
-    def __init__(self, params, m: ref.Model, tables, rag: Dict,
+    def __init__(self, lm, params, m, tables, rag: Dict,
                  sample: Sequence, max_seq: int):
-        self.params, self.m, self.tables, self.rag = params, m, tables, rag
+        self.lm, self.params, self.m = lm, params, m
+        self.tables, self.rag = tables, rag
         self.toks, self.where, self.keys = _positions(sample, max_seq)
         self.served = jnp.asarray(np.concatenate(
             [np.asarray(r.tokens, np.int32) for r in sample]))
-        self.h = hidden_at(params, m, self.toks, self.where, ref.REFERENCE)
-        self.lm = _logits(params, self.h, m, ref.REFERENCE)
+        self.h = hidden_at(lm, params, m, self.toks, self.where,
+                           ref.REFERENCE)
+        self.logits = _logits(lm, params, self.h, m, ref.REFERENCE)
 
 
 def readings(reference: Reference, rows: List[Tuple]) -> Dict[str, float]:
@@ -283,7 +288,7 @@ def readings(reference: Reference, rows: List[Tuple]) -> Dict[str, float]:
         return dict(missing=float(unrecorded))
     q, d, i = (jnp.asarray(np.stack([found[j][n] for j in at]).astype(t))
                for n, t in enumerate((np.float32, np.float32, np.int32)))
-    h, lm, tok = r.h[at], r.lm[at], r.served[at]
+    h, logits, tok = r.h[at], r.logits[at], r.served[at]
     d_ref = _dists(r.tables, q, i, ref.REFERENCE)
     missing = unrecorded + jnp.sum(jnp.any((i < 0) | ~jnp.isfinite(d), -1))
     out = dict(
@@ -291,7 +296,7 @@ def readings(reference: Reference, rows: List[Tuple]) -> Dict[str, float]:
         dist_err=jnp.max(_dist_err(d, d_ref)),
         scan_gap=jnp.max(_scan_gap(r.tables, q, i, d_ref, r.rag["nprobe"],
                                    r.rag["k"])),
-        mix_gap=jnp.max(_gap(_mix(r.tables, lm, d, i, r.rag), tok)),
+        mix_gap=jnp.max(_gap(_mix(r.tables, logits, d, i, r.rag), tok)),
         missing=missing)
     return {k: float(v) for k, v in out.items()}
 
@@ -301,19 +306,19 @@ def control_readings(reference: Reference) -> Dict[str, float]:
     the configuration states, in the program's place: fp8 matmuls in the
     LM, bf16 distance tables and probe in the search."""
     r = reference
-    h = hidden_at(r.params, r.m, r.toks, r.where, ref.CONTROL)
-    lm = _logits(r.params, h, r.m, ref.CONTROL)
+    h = hidden_at(r.lm, r.params, r.m, r.toks, r.where, ref.CONTROL)
+    logits = _logits(r.lm, r.params, h, r.m, ref.CONTROL)
     d, i = _search(r.tables, h, r.rag["nprobe"], r.rag["k"], ref.CONTROL)
     d_ref = _dists(r.tables, h, i, ref.REFERENCE)
     # the control's greedy token, judged as the program's is: under the
     # reference's LM mixed with the neighbours the control found
-    tok = jnp.argmax(_mix(r.tables, lm, d, i, r.rag), -1)
+    tok = jnp.argmax(_mix(r.tables, logits, d, i, r.rag), -1)
     out = dict(
         query_err=jnp.max(_rel(h, r.h)),
         dist_err=jnp.max(_dist_err(d, d_ref)),
         scan_gap=jnp.max(_scan_gap(r.tables, h, i, d_ref, r.rag["nprobe"],
                                    r.rag["k"])),
-        mix_gap=jnp.max(_gap(_mix(r.tables, r.lm, d, i, r.rag), tok)))
+        mix_gap=jnp.max(_gap(_mix(r.tables, r.logits, d, i, r.rag), tok)))
     return {k: float(v) for k, v in out.items()}
 
 

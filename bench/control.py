@@ -31,7 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--control-seeds", default="")
     args = ap.parse_args(argv)
-    _, cell, cfg, traffic = run.load_cell(run.ROOT, args.workload)
+    _, cell, cfg, traffic, lm = run.load_cell(run.ROOT, args.workload)
     try:
         run.require_chips(cell["chips"])
     except run.NoChip as e:
@@ -47,7 +47,7 @@ def main(argv=None) -> int:
     for seed in seeds:
         plan = loadgen.plan(traffic, args.seconds, seed,
                             cfg["model"]["vocab_size"])
-        server = run.Server(cfg, traffic, seed, compiles, plan)
+        server = run.Server(cfg, lm, traffic, seed, compiles, plan)
         w = server.window(plan, args.seconds)
         server.close()
         failed = sum(not r.complete for r in w["results"])

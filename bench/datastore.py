@@ -40,19 +40,20 @@ ENCODE_ROWS = 1 << 14        # keys PQ-encoded per block
 SAMPLE_DOCS = 16             # documents per hidden-state forward block
 
 
-def sample_hidden(params, model: ref.Model, key, n_docs: int, doc_len: int
+def sample_hidden(lm, params, model, key, n_docs: int, doc_len: int
                   ) -> jnp.ndarray:
-    """Hidden states [n_docs * doc_len, d] of seeded random documents,
-    in blocks of ``SAMPLE_DOCS`` documents (bf16 matmuls)."""
+    """Hidden states [n_docs * doc_len, d] of seeded random documents
+    under the LM module ``lm``, in blocks of ``SAMPLE_DOCS`` documents
+    (bf16 matmuls)."""
     toks = jax.random.randint(key, (n_docs // SAMPLE_DOCS, SAMPLE_DOCS,
                                     doc_len), 0, model.vocab_size)
-    return _sample_hidden(params, toks, model)
+    return _sample_hidden(params, toks, model, lm)
 
 
-@functools.partial(jax.jit, static_argnums=2)
-def _sample_hidden(params, toks, model):
-    h = jax.lax.map(lambda t: ref.forward_hidden(params, t, model,
-                                                 ref.SAMPLE), toks)
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _sample_hidden(params, toks, model, lm):
+    h = jax.lax.map(lambda t: lm.forward_hidden(params, t, model,
+                                                ref.SAMPLE), toks)
     return h.reshape(-1, model.d_model)
 
 
@@ -141,18 +142,18 @@ def encode_lists(key, centroids, codebooks, offsets, lens, sigma,
     return codes.astype(jnp.uint8), ids
 
 
-def build(params, model: ref.Model, ds: Dict, key) -> Tuple[ref.Tables,
-                                                             Dict]:
-    """Make the cell's datastore from ``key``. ``ds`` is the
+def build(lm, params, model, ds: Dict, key) -> Tuple[ref.Tables, Dict]:
+    """Make the cell's datastore from ``key``, sampling hidden states with
+    the LM module ``lm`` (its ``Model`` is ``model``). ``ds`` is the
     configuration's ``datastore`` block. Returns the tables and a summary
     (sizes, capacity, skew) for the log."""
     k_cent, k_cb, k_len, k_keys, k_pay = jax.random.split(key, 5)
     nlist, n, m = ds["nlist"], ds["vectors"], ds["m"]
     doc_len = ds["sample_doc_len"]
-    centroids = sample_hidden(params, model, k_cent, nlist // doc_len,
+    centroids = sample_hidden(lm, params, model, k_cent, nlist // doc_len,
                               doc_len)
     codebooks = train_codebooks(k_cb, centroids, m, ds["ksub"])
-    sample = sample_hidden(params, model, k_len,
+    sample = sample_hidden(lm, params, model, k_len,
                            ds["spread_sample_tokens"] // doc_len, doc_len)
     sigma = float(np.sqrt(float(spread(sample, centroids)) / model.d_model))
     del sample

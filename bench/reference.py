@@ -1,22 +1,23 @@
-"""The benchmark's plain reference: a decoder-only kNN-LM written out in
-``jax.numpy``, independent of the program under test.
+"""The benchmark's plain reference of the kNN-LM's architecture-independent
+part, written out in ``jax.numpy``, independent of the program under test.
 
-It imports nothing of the program. It holds:
+It imports nothing of the program, and nothing of ``bench/lm/``. It holds:
 
-* ``make_weights``: the seeded random weights the cell serves (bf16), in
-  the parameter layout the program's dense decoder reads;
-* ``forward_hidden``: the causal forward pass (pre-norm RMSNorm, rotary
-  positions, softmax attention, SwiGLU, tied embeddings) at a chosen
-  precision: float32 at ``highest`` for the reference, scaled fp8 for the
-  lower-precision control, plain bf16 for making datastore samples;
+* ``seed_key``, and ``matmul`` at the three precisions the LM modules
+  (``bench/lm/<name>.py``) compute at: float32 at ``highest`` for the
+  reference, scaled fp8 for the lower-precision control, plain bf16 for
+  making datastore samples;
 * the kNN search over the benchmark's own IVF-PQ tables: exact probe,
   asymmetric distance (ADC) by table lookup, exact top-K;
 * the kNN-LM mixture ``log((1 - lam) softmax(lm) + lam p_knn)``.
+
+The LM itself, its weights and its forward pass, is the configuration's
+LM module (see ``bench/lm/dense.py`` for what one provides).
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -26,28 +27,10 @@ BF16 = jnp.bfloat16
 FP8 = jnp.float8_e4m3fn
 FP8_MAX = 448.0
 
-#: precisions of ``forward_hidden`` and of the search
+#: precisions of an LM module's ``forward_hidden`` and of the search
 REFERENCE = "f32"       # float32 at highest: the reference
 CONTROL = "fp8"         # scaled float8 e4m3 matmuls: the control
 SAMPLE = "bf16"         # bf16 at the default precision: datastore samples
-
-
-class Model(NamedTuple):
-    """The model sizes the reference needs (from the configuration file)."""
-    n_layers: int
-    d_model: int
-    n_heads: int
-    n_kv_heads: int
-    d_head: int
-    d_ff: int
-    vocab_size: int
-    rope_theta: float = 1e4
-    norm_eps: float = 1e-5
-    init_scale: float = 0.02
-
-    @classmethod
-    def from_config(cls, model: Dict) -> "Model":
-        return cls(**{f: model[f] for f in cls._fields if f in model})
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -55,28 +38,6 @@ def seed_key(seed: int) -> jax.Array:
     32 bits, which ``PRNGKey`` alone would truncate)."""
     return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF),
                               (seed >> 32) & 0xFFFFFFFF)
-
-
-@functools.partial(jax.jit, static_argnums=1)
-def make_weights(key: jax.Array, m: Model) -> Dict:
-    """Seeded bf16 weights, N(0, init_scale) matrices and unit norms, in
-    one jitted call on the device."""
-    L, d, f = m.n_layers, m.d_model, m.d_ff
-    H, KV, dh = m.n_heads, m.n_kv_heads, m.d_head
-    ks = jax.random.split(key, 8)
-
-    def normal(k, shape):
-        return (jax.random.normal(k, shape, F32) * m.init_scale).astype(BF16)
-
-    layer = dict(
-        ln1=jnp.ones((L, d), BF16), ln2=jnp.ones((L, d), BF16),
-        wq=normal(ks[1], (L, d, H * dh)), wk=normal(ks[2], (L, d, KV * dh)),
-        wv=normal(ks[3], (L, d, KV * dh)), wo=normal(ks[4], (L, H * dh, d)),
-        wg=normal(ks[5], (L, d, f)), wu=normal(ks[6], (L, d, f)),
-        wd=normal(ks[7], (L, f, d)))
-    return {"embed": normal(ks[0], (m.vocab_size, d)),
-            "final_norm": jnp.ones((d,), BF16),
-            "classes": {"global": layer}}
 
 
 # ---------------------------------------------------------------------------
@@ -103,65 +64,6 @@ def matmul(x: jnp.ndarray, w: jnp.ndarray, prec: str) -> jnp.ndarray:
                           precision=jax.lax.Precision.HIGHEST)
     return jnp.matmul(x.astype(BF16), w.astype(BF16),
                       preferred_element_type=F32)
-
-
-def _rms_norm(x, scale, eps):
-    x = x.astype(F32)
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) \
-        * scale.astype(F32)
-
-
-def _rope(x, positions, theta):
-    """Rotate-half rotary positions. x [B, T, H, D], positions [T]."""
-    half = x.shape[-1] // 2
-    inv = 1.0 / theta ** (jnp.arange(0, 2 * half, 2, dtype=F32) / (2 * half))
-    ang = positions.astype(F32)[:, None] * inv                  # [T, half]
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = x[..., :half], x[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def forward_hidden(params: Dict, tokens: jnp.ndarray, m: Model,
-                   prec: str) -> jnp.ndarray:
-    """Last-layer hidden states [B, T, d] (before the final norm; the
-    kNN-LM query) of a causal pass over ``tokens`` [B, T]. Layers run one
-    at a time under ``lax.scan``, each layer's weights widened there, so
-    the pass needs one layer's float32 copy at a time."""
-    B, T = tokens.shape
-    H, KV, dh = m.n_heads, m.n_kv_heads, m.d_head
-    pos = jnp.arange(T)
-    causal = pos[:, None] >= pos[None, :]
-    h = params["embed"][tokens].astype(F32)
-
-    def layer(h, p):
-        hn = _rms_norm(h, p["ln1"], m.norm_eps)
-        q = matmul(hn, p["wq"], prec).reshape(B, T, H, dh)
-        k = matmul(hn, p["wk"], prec).reshape(B, T, KV, dh)
-        v = matmul(hn, p["wv"], prec).reshape(B, T, KV, dh)
-        q, k = _rope(q, pos, m.rope_theta), _rope(k, pos, m.rope_theta)
-        k = jnp.repeat(k, H // KV, axis=2)
-        v = jnp.repeat(v, H // KV, axis=2)
-        s = jnp.einsum("bthd,bshd->bhts", q, k,
-                       precision=jax.lax.Precision.HIGHEST) * dh ** -0.5
-        s = jnp.where(causal[None, None], s, -jnp.inf)
-        a = jnp.einsum("bhts,bshd->bthd", jax.nn.softmax(s, -1), v,
-                       precision=jax.lax.Precision.HIGHEST)
-        h = h + matmul(a.reshape(B, T, H * dh), p["wo"], prec)
-        hn = _rms_norm(h, p["ln2"], m.norm_eps)
-        g = jax.nn.silu(matmul(hn, p["wg"], prec)) * matmul(hn, p["wu"], prec)
-        return h + matmul(g, p["wd"], prec), None
-
-    h, _ = jax.lax.scan(layer, h, params["classes"]["global"])
-    return h
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def lm_logits(params: Dict, hidden: jnp.ndarray, m: Model, prec: str
-              ) -> jnp.ndarray:
-    """Tied-embedding logits [N, V] of hidden states [N, d]."""
-    hn = _rms_norm(hidden, params["final_norm"], m.norm_eps)
-    return matmul(hn, params["embed"].T, prec)
 
 
 # ---------------------------------------------------------------------------

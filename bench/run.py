@@ -17,7 +17,8 @@ the cell's per-layer ones.
 
 Everything that belongs to one configuration, traffic mix or metric is a
 file of its own, found by the names in ``BENCHMARK.json``: the
-configuration's ``file``, ``bench/traffic/<traffic>.json`` and
+configuration's ``file``, the LM module it names
+(``bench/lm/<lm>.py``), ``bench/traffic/<traffic>.json`` and
 ``bench/metrics/<metric>.py`` (see ``bench/README.md``).
 
 Without a TPU, or with fewer chips than the cell asks for, it exits with
@@ -30,6 +31,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -46,6 +48,9 @@ sys.path.insert(0, str(ROOT / "src"))
 
 NO_CHIP = 3
 TRACE_DIR = HERE / ".traces"
+#: keys of a configuration's ``model`` block that are the harness's own,
+#: not the engine's: the weights' scale and the LM module's name
+HARNESS_MODEL_KEYS = ("init_scale", "lm")
 
 
 def log(**fields) -> None:
@@ -68,6 +73,11 @@ def require_chips(n: int) -> List:
 
 
 def load_cell(root: pathlib.Path, workload: str):
+    """The cell's entry, configuration, traffic mix and LM module, found
+    under ``root``. A configuration the harness cannot serve (an LM
+    module with no file, a ``model`` key the engine does not take, a
+    model block its LM module refuses) is refused here, before any
+    device work."""
     bench = json.loads((root / "BENCHMARK.json").read_text())
     cell = next((w for w in bench["workloads"] if w["name"] == workload),
                 None)
@@ -77,7 +87,43 @@ def load_cell(root: pathlib.Path, workload: str):
     cfg = json.loads((root / entry["file"]).read_text())
     traffic = json.loads((root / "bench" / "traffic" /
                           f"{cell['traffic']}.json").read_text())
-    return bench, cell, cfg, traffic
+    lm = lm_module(root, cfg["model"].get("lm", "dense"))
+    model_config(cfg)
+    try:
+        lm.Model.from_config(cfg["model"])
+    except ValueError as e:
+        raise SystemExit(f"configuration {cfg['name']!r}: {e}") from None
+    return bench, cell, cfg, traffic, lm
+
+
+def lm_module(root: pathlib.Path, name: str):
+    """The LM module ``<root>/bench/lm/<name>.py`` (see the docstring of
+    ``bench/lm/dense.py`` for what it provides)."""
+    path = (root / "bench" / "lm" / f"{name}.py").resolve()
+    if not path.is_file():
+        raise SystemExit(f"the configuration names the LM {name!r}, but "
+                         f"there is no {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_lm_{name.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_config(cfg: Dict):
+    """The program's ``ModelConfig`` from every key of the configuration's
+    ``model`` block but the harness's own (JSON lists as tuples), named
+    as the configuration is. A key it has no field for raises."""
+    from repro.models.config import ModelConfig
+    fields = {f.name for f in dataclasses.fields(ModelConfig)} - {"name"}
+    keys = {k: v for k, v in cfg["model"].items()
+            if k not in HARNESS_MODEL_KEYS}
+    unknown = sorted(set(keys) - fields)
+    if unknown:
+        raise SystemExit(f"configuration {cfg['name']!r}: the engine's "
+                         f"ModelConfig takes no model key {unknown}")
+    return ModelConfig(name=cfg["name"], **{
+        k: tuple(v) if isinstance(v, list) else v for k, v in keys.items()})
 
 
 def cell_metrics(bench: Dict, cell: Dict, trace: bool) -> List[Dict]:
@@ -139,19 +185,12 @@ def build_engine(cfg: Dict, params, tables, summary: Dict):
     service-backed retriever, no per-stage blocking, a fixed KV pool."""
     from repro.core.ivfpq import IVFPQConfig, IVFPQParams, IVFPQShard
     from repro.core.rag import RagConfig
-    from repro.models.config import ModelConfig
     from repro.serve import EngineConfig, RalmEngine
     from repro.serve.datastore import Datastore
 
-    mc, ds, eng, rag = cfg["model"], cfg["datastore"], cfg["engine"], \
-        cfg["rag"]
-    model = ModelConfig(name=cfg["name"], n_layers=mc["n_layers"],
-                        d_model=mc["d_model"], n_heads=mc["n_heads"],
-                        n_kv_heads=mc["n_kv_heads"], d_ff=mc["d_ff"],
-                        vocab_size=mc["vocab_size"], d_head=mc["d_head"],
-                        rope_theta=mc["rope_theta"],
-                        norm_eps=mc["norm_eps"], tie_embeddings=True)
-    icfg = IVFPQConfig(dim=mc["d_model"], nlist=ds["nlist"], m=ds["m"],
+    ds, eng, rag = cfg["datastore"], cfg["engine"], cfg["rag"]
+    model = model_config(cfg)
+    icfg = IVFPQConfig(dim=model.d_model, nlist=ds["nlist"], m=ds["m"],
                        nbits=8, residual=False, list_cap=summary["list_cap"])
     store = Datastore(
         params=IVFPQParams(tables.centroids, tables.codebooks),
@@ -239,9 +278,9 @@ def warm(engine, plan, eng: Dict, vocab: int, compiles) -> None:
 
 
 class Context:
-    """What a metric reader gets: the run's requests and window, the
-    server's counters over it, and with ``--trace 1`` the reduced
-    trace."""
+    """What a metric reader gets: the configuration and its LM module
+    (``lm``), the run's requests and window, the server's counters over
+    it, and with ``--trace 1`` the reduced trace."""
 
     def __init__(self, **kw):
         self.__dict__.update(kw)
@@ -254,10 +293,11 @@ class Context:
 
 
 class Server:
-    """One seed's weights and datastore, the engine over them behind the
-    HTTP gateway, warmed up for the requests of ``plan``."""
+    """One seed's weights and datastore, made with the configuration's LM
+    module ``lm``, the engine over them behind the HTTP gateway, warmed
+    up for the requests of ``plan``."""
 
-    def __init__(self, cfg: Dict, traffic: Dict, seed: int, compiles,
+    def __init__(self, cfg: Dict, lm, traffic: Dict, seed: int, compiles,
                  plan, annotated: bool = False):
         import jax
 
@@ -266,12 +306,12 @@ class Server:
         import reference as ref
         from repro.serve import Gateway, GatewayConfig
 
-        self.cfg, self.traffic, self.seed = cfg, traffic, seed
-        self.model = ref.Model.from_config(cfg["model"])
+        self.cfg, self.lm, self.traffic, self.seed = cfg, lm, traffic, seed
+        self.model = lm.Model.from_config(cfg["model"])
         eng = cfg["engine"]
         k_w, k_d = jax.random.split(ref.seed_key(seed))
-        self.params = ref.make_weights(k_w, self.model)
-        self.tables, summary = datastore.build(self.params, self.model,
+        self.params = lm.make_weights(k_w, self.model)
+        self.tables, summary = datastore.build(lm, self.params, self.model,
                                                cfg["datastore"], k_d)
         jax.block_until_ready(self.tables)
         log(phase="datastore", s=time.perf_counter() - T_START, **summary)
@@ -349,7 +389,7 @@ class Server:
         if not sample:
             return {}
         self.reference = check.Reference(
-            self.params, self.model, self.tables,
+            self.lm, self.params, self.model, self.tables,
             dict(self.cfg["rag"], nprobe=self.cfg["datastore"]["nprobe"]),
             sample, self.cfg["engine"]["max_seq"])
         values = check.readings(self.reference, rows)
@@ -370,7 +410,7 @@ def main(argv: Optional[List[str]] = None,
                     help="also write the reduced trace (JSON) here")
     args = ap.parse_args(argv)
 
-    bench, cell, cfg, traffic = load_cell(root, args.workload)
+    bench, cell, cfg, traffic, lm = load_cell(root, args.workload)
     if cell["chips"] != 1:
         # the engine is stood up monolithic on one chip; a cell on more
         # chips needs a topology (LM and retrieval devices) that
@@ -393,7 +433,7 @@ def main(argv: Optional[List[str]] = None,
 
     plan = loadgen.plan(traffic, args.seconds, args.seed,
                         cfg["model"]["vocab_size"])
-    server = Server(cfg, traffic, args.seed, compiles, plan,
+    server = Server(cfg, lm, traffic, args.seed, compiles, plan,
                     annotated=bool(args.trace))
     w = server.window(plan, args.seconds, TRACE_DIR if args.trace else None)
     mem = [d.memory_stats() or {} for d in devices]
@@ -402,7 +442,8 @@ def main(argv: Optional[List[str]] = None,
 
     peak = lookup(devices[0].device_kind) if devices[0].platform == "tpu" \
         else None
-    ctx = Context(cfg=cfg, traffic=traffic, setup_s=w["t0"] - T_START,
+    ctx = Context(cfg=cfg, lm=lm, traffic=traffic,
+                  setup_s=w["t0"] - T_START,
                   peak=peak, chips=len(devices),
                   tables=server.tables, trace=None, win=None, planes=[], **w)
     device = {"platform": devices[0].platform, "kind": devices[0].device_kind,

@@ -31,7 +31,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--rates", required=True)
     args = ap.parse_args(argv)
-    _, cell, cfg, traffic = run.load_cell(run.ROOT, args.workload)
+    _, cell, cfg, traffic, lm = run.load_cell(run.ROOT, args.workload)
     try:
         run.require_chips(cell["chips"])
     except run.NoChip as e:
@@ -41,7 +41,8 @@ def main(argv=None) -> int:
     import loadgen
     vocab = cfg["model"]["vocab_size"]
     base = loadgen.plan(traffic, args.seconds, args.seed, vocab)
-    server = run.Server(cfg, traffic, args.seed, run.CompileCounter(), base)
+    server = run.Server(cfg, lm, traffic, args.seed, run.CompileCounter(),
+                        base)
     for rate in [float(r) for r in args.rates.split(",")]:
         seconds = len(base) / rate
         w = server.window(loadgen.plan(dict(traffic, rate_per_s=rate),

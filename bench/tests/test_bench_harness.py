@@ -18,8 +18,11 @@ sys.path.insert(0, str(BENCH / "tests"))
 import devtrace  # noqa: E402
 import loadgen  # noqa: E402
 import peaks  # noqa: E402
+import run  # noqa: E402
 import tiny  # noqa: E402
 import work  # noqa: E402
+
+dense = run.lm_module(BENCH.parent, "dense")
 
 FIXTURE = BENCH / "tests" / "trace_fixture.json"
 
@@ -29,7 +32,6 @@ def run_tiny(tmp_path, monkeypatch, capsys, seed=20261016):
     persistent compile cache switched off; returns (exit code, the last
     stdout line as JSON, stderr)."""
     import jax
-    import run
     monkeypatch.setattr(run, "require_chips",
                         lambda n: jax.devices()[:n])
     monkeypatch.setattr(run, "compile_cache", lambda: None)
@@ -141,10 +143,10 @@ MODEL = {"n_layers": 2, "d_model": 8, "n_heads": 2, "n_kv_heads": 1,
 
 def test_lm_and_prefill_work_by_hand():
     block = 8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16
-    assert work.lm_token(MODEL, kv_len=3) == \
+    assert dense.lm_token(MODEL, kv_len=3) == \
         2 * 2 * block + 2 * 8 * 10 + 2 * 4 * 3 * 2 * 4
     # prefill of 3 positions: attention over 1 + 2 + 3 positions
-    assert work.prefill(MODEL, 3) == \
+    assert dense.prefill(MODEL, 3) == \
         2 * 2 * block * 3 + 2 * 4 * 2 * 4 * 6 + 2 * 8 * 10
     assert work.retrieval_token({"nlist": 10, "m": 4, "ksub": 16}, 8) == \
         2 * 10 * 8 + 2 * 4 * 16 * 2
@@ -163,10 +165,10 @@ def test_step_mfu_by_hand():
     ds = {"nlist": 10, "m": 4, "ksub": 16}
     req = types.SimpleNamespace(request=types.SimpleNamespace(prompt=[0] * 3))
     ctx = types.SimpleNamespace(
-        cfg={"model": MODEL, "datastore": dict(ds, dim=8)},
+        cfg={"model": MODEL, "datastore": dict(ds, dim=8)}, lm=dense,
         peak={"bf16_flops": 1e6}, chips=1, win=(0.0, 2e9), t0=0.0,
         t_end=2.0, tokens=lambda lo, hi: [(req, 0, 0.5), (req, 1, 1.0)])
-    ops = (work.prefill(MODEL, 3) + work.lm_token(MODEL, 4)
+    ops = (dense.prefill(MODEL, 3) + dense.lm_token(MODEL, 4)
            + 2 * work.retrieval_token(ds, 8))
     assert mod.read(ctx) == pytest.approx(100.0 * ops / 2.0 / 1e6)
     ctx.win = None
@@ -178,7 +180,6 @@ def test_compile_counter_sees_a_program_built():
     counts, a call that finds it already built does not."""
     import jax
     import jax.numpy as jnp
-    import run
     counter = run.CompileCounter()
     before = counter.count
     f = jax.jit(lambda x: x * 3 + 1)

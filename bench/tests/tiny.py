@@ -14,7 +14,8 @@ CONFIG = {
     "source": "reduced widths for CPU tests",
     "model": {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
               "d_head": 16, "d_ff": 128, "vocab_size": 512,
-              "rope_theta": 10000.0, "norm_eps": 1e-5, "init_scale": 0.02},
+              "rope_theta": 10000.0, "norm_eps": 1e-5, "init_scale": 0.02,
+              "tie_embeddings": True},
     "rag": {"mode": "knnlm", "interval": 1, "k": 8, "lam": 0.25,
             "temperature": 10.0},
     "datastore": {"vectors": 8192, "dim": 64, "m": 8, "ksub": 256,
@@ -33,13 +34,17 @@ TRAFFIC = {
 }
 
 
-def make_root(tmp: pathlib.Path, traffic=None) -> pathlib.Path:
+def make_root(tmp: pathlib.Path, traffic=None, config=None) -> pathlib.Path:
     """A root holding BENCHMARK.json with the one tiny cell, its
-    configuration and its traffic file (the metric readers are the
-    harness's own)."""
+    configuration (``CONFIG`` unless given), its traffic file and a copy
+    of the harness's LM modules (the metric readers are the harness's
+    own)."""
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
-    (tmp / "bench" / "configs" / "tiny.json").write_text(json.dumps(CONFIG))
+    shutil.copytree(BENCH / "lm", tmp / "bench" / "lm",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp / "bench" / "configs" / "tiny.json").write_text(
+        json.dumps(config or CONFIG))
     (tmp / "bench" / "traffic" / "open.json").write_text(
         json.dumps(traffic or TRAFFIC))
     bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
