@@ -30,6 +30,17 @@ when ``i > pos`` while the ring has not wrapped, and never invalid
 after it wraps — at which point ``max(position) >= S - 1`` keeps every
 block live).
 
+**Latent mode** (``fused_latent_decode_attention``, kernel name
+``decode_attn_latent``): multi-head latent attention decodes every query
+head against one cached latent row per position, ``[normed c_kv |
+roped k_pe]``. Each wave row streams its own slot's rows straight from
+the pool (slots and positions in SMEM; no gathered copy), ``[blk, W]``
+a step; scores contract the whole row with the absorbed query, and the
+values are the same block's first ``v_dim`` lanes, never a second copy.
+Online softmax and the length-aware block skip are the dense mode's
+(per row here, and a skipped block is not fetched); a latent cache is
+linear (no ring, no window).
+
 Validated against the grouped ``ref`` oracle and the legacy einsum path
 in ``tests/test_decode_attn.py`` (hypothesis property test). Both
 in-kernel einsums have one batch dim (the wave row), which is what
@@ -177,3 +188,90 @@ def fused_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = jnp.einsum("bhkd,hk->bhd", out.reshape(B, H, KV, D),
                      onehot.astype(out.dtype))
     return out[:, None].astype(v_cache.dtype)
+
+
+def _latent_decode_kernel(slot_ref, pos_ref, q_ref, c_ref, out_ref, acc_ref,
+                          m_ref, l_ref, *, blk: int, v_dim: int,
+                          scale: float):
+    i = pl.program_id(0)
+    j = pl.program_id(1)
+    nb = pl.num_programs(1)
+    pos = pos_ref[i]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(j * blk <= pos)
+    def _block():
+        q = q_ref[0].astype(jnp.float32)                  # [H, W]
+        c = c_ref[0].astype(jnp.float32)                  # [blk, W]
+        s = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        slot = j * blk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = slot <= pos                               # [H, blk]
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_ref[...]                               # [H, 1]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        l_ref[...] = l_ref[...] * corr + p.sum(-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jnp.dot(
+            p, c[:, :v_dim], preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(j == nb - 1)
+    def _final():
+        out_ref[0] = acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+
+
+@functools.partial(jax.jit, static_argnames=("kv_len", "scale", "v_dim",
+                                             "blk", "interpret"))
+def fused_latent_decode_attention(q: jnp.ndarray, cache: jnp.ndarray,
+                                  slots: jnp.ndarray, position: jnp.ndarray,
+                                  kv_len: int, scale: float, v_dim: int,
+                                  blk: int = 128,
+                                  interpret: bool = True) -> jnp.ndarray:
+    """Latent-mode decode attention for a whole wave, read straight from
+    the pool.
+
+    q [B, H, W] (each head's absorbed no-rope query, then its roped
+    query) | cache [P, S, W] (the pool; row i of a slot: position i's
+    latent, then its roped key) | slots, position [B] int32 -> [B, H,
+    v_dim] float32: each head's softmax-weighted sum of the first
+    ``v_dim`` lanes of its slot's rows ``0..position``. The grid streams
+    the first ``kv_len`` rows of each wave row's slot, ``blk`` at a time
+    (``blk`` divides ``kv_len``); blocks past a row's position are
+    skipped, and their index clamps to its last live block, so they are
+    not fetched either."""
+    B, H, W = q.shape
+    assert kv_len % blk == 0 and kv_len <= cache.shape[1], (kv_len, blk)
+    kernel = functools.partial(_latent_decode_kernel, blk=blk, v_dim=v_dim,
+                               scale=scale)
+
+    def rows(i, j, s, p):
+        return s[i], jnp.minimum(j, p[i] // blk), 0
+
+    return pl.pallas_call(
+        kernel,
+        name="decode_attn_latent",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(B, kv_len // blk),
+            in_specs=[
+                pl.BlockSpec((1, H, W), lambda i, j, s, p: (i, 0, 0)),
+                pl.BlockSpec((1, blk, W), rows),
+            ],
+            out_specs=pl.BlockSpec((1, H, v_dim),
+                                   lambda i, j, s, p: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((H, v_dim), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32),
+                            pltpu.VMEM((H, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, H, v_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.asarray(slots, jnp.int32).reshape(B),
+      jnp.asarray(position, jnp.int32).reshape(B), q, cache)

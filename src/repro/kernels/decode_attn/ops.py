@@ -9,6 +9,7 @@ contract, and fallback accounting.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax.numpy as jnp
@@ -17,6 +18,9 @@ import numpy as np
 from repro.kernels import registry
 from repro.kernels.decode_attn import kernel as _k
 from repro.kernels.decode_attn import ref as _ref
+
+#: positions per streamed block of the latent mode
+LATENT_BLOCK = 256
 
 
 def pallas_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -43,6 +47,24 @@ def pallas_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
         q, k_cache, v_cache, position, window=window, ring=ring,
         tile_b=spec.pick_tile_q(B), blk=spec.pick_block_seq(S),
         interpret=spec.use_interpret())
+
+
+def pallas_latent_decode_attention(q: jnp.ndarray, cache: jnp.ndarray,
+                                   slots: jnp.ndarray,
+                                   position: jnp.ndarray, kv_len: int,
+                                   scale: float, v_dim: int,
+                                   spec: Optional[registry.KernelSpec] = None
+                                   ) -> jnp.ndarray:
+    """Latent-mode streaming decode attention, one dispatch for the wave:
+    q [B, H, W] | pool cache [P, S, W] | slots, position [B] ->
+    [B, H, v_dim]. Rows stream in blocks of ``LATENT_BLOCK`` (or the
+    spec's ``tile_n``), the largest that divides ``kv_len``: one wave row
+    per grid row, so a block is 256 x 576 bf16 at DeepSeek-V2's widths."""
+    spec = registry.resolve("decode_attn", spec)
+    blk = math.gcd(kv_len, spec.tile_n or LATENT_BLOCK)
+    return _k.fused_latent_decode_attention(
+        q, cache, slots, position, kv_len=kv_len, scale=scale, v_dim=v_dim,
+        blk=blk, interpret=spec.use_interpret())
 
 
 def count_skipped_blocks(positions: np.ndarray, S: int, blk: int,

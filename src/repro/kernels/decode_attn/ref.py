@@ -64,3 +64,19 @@ def ref_decode_attention(q: jnp.ndarray, k_cache: jnp.ndarray,
     w = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bkgts,bskd->btkgd", w.astype(v_cache.dtype), v_cache)
     return out.reshape(B, T, H, D)
+
+
+def ref_latent_decode_attention(q: jnp.ndarray, cache: jnp.ndarray,
+                                slots: jnp.ndarray, position: jnp.ndarray,
+                                kv_len: int, scale: float, v_dim: int
+                                ) -> jnp.ndarray:
+    """Latent-mode oracle: q [B, H, W] against rows ``0..position`` of
+    pool slots ``slots`` of cache [P, S, W] (linear; the first ``kv_len``
+    rows read) -> [B, H, v_dim] float32, the values being each row's
+    first ``v_dim`` lanes."""
+    c = cache[:, :kv_len][slots]
+    s = jnp.einsum("bhw,bsw->bhs", q, c,
+                   preferred_element_type=jnp.float32) * scale
+    valid = decode_validity(position, kv_len, 0, False)
+    w = jax.nn.softmax(jnp.where(valid[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhs,bsv->bhv", w, c[..., :v_dim].astype(jnp.float32))

@@ -1,4 +1,5 @@
-"""Attention: GQA with full / sliding-window masks.
+"""Attention: GQA with full / sliding-window masks, and multi-head latent
+attention (MLA, DeepSeek-V2 arXiv 2405.04434 §2.1).
 
 Execution paths:
   * ``flash_attention`` — blocked online-softmax over (q-block, kv-block)
@@ -15,6 +16,14 @@ Execution paths:
     max valid position), ``backend="einsum"`` the legacy
     full-materialization path kept as the parity oracle
     (``decode_attention_einsum``).
+
+MLA caches one row per position, ``[rms_norm(c_kv) | rope(k_pe)]``
+(``kv_lora_rank + qk_rope_head_dim`` wide). Prefill expands it to per-head
+keys and values (``mla_prefill``, flash attention); decode takes the
+absorbed form (``mla_decode``): each head's no-rope query times its slice
+of W_UK scores the cached latent directly, and the attention-weighted
+latent times W_UV is the head's value, through the ``decode_attn``
+kernel's latent mode.
 """
 from __future__ import annotations
 
@@ -25,10 +34,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import registry
-from repro.kernels.decode_attn.ops import pallas_decode_attention
+from repro.kernels.decode_attn.ops import (pallas_decode_attention,
+                                           pallas_latent_decode_attention)
 from repro.kernels.decode_attn.ref import (decode_validity,
-                                           ref_decode_attention)
+                                           ref_decode_attention,
+                                           ref_latent_decode_attention)
 from repro.models.ctx import constrain, kv_tags
+from repro.models.layers import apply_rope_pairs, rms_norm, yarn_inv_freq
 
 NEG_INF = -1e30
 
@@ -92,9 +104,12 @@ def _flash_fwd_stats(q, k, v, q_positions, k_positions, causal, window,
 
 
 def _flash_forward(q, k, v, q_positions, k_positions, causal=True, window=0,
-                   q_block=512, k_block=512):
-    """Returns (out [B,T,H,D], lse [B,T,H]) — log-sum-exp per row for bwd."""
+                   q_block=512, k_block=512, scale=None):
+    """Returns (out [B,T,H,Dv], lse [B,T,H]) — log-sum-exp per row for bwd.
+    ``v`` may be narrower than q and k (latent attention's value heads);
+    ``scale`` defaults to ``D ** -0.5``."""
     B, T, H, D = q.shape
+    Dv = v.shape[-1]
     S = k.shape[1]
     qkv = H // k.shape[2]
     q_block = min(q_block, T)
@@ -124,9 +139,9 @@ def _flash_forward(q, k, v, q_positions, k_positions, causal=True, window=0,
     qb = qp.reshape(B, nq, q_block, H, D)
     qbpos = qpos.reshape(B, nq, q_block)
     kb = kp.reshape(B, nk, k_block, H, D)
-    vbv = vp.reshape(B, nk, k_block, H, D)
+    vbv = vp.reshape(B, nk, k_block, H, Dv)
     kbpos = kpos.reshape(B, nk, k_block)
-    scale = D ** -0.5
+    scale = D ** -0.5 if scale is None else scale
 
     def q_step(_, qi):
         qblk, qbp = qi                                       # [B,qb,H,D],[B,qb]
@@ -153,7 +168,7 @@ def _flash_forward(q, k, v, q_positions, k_positions, causal=True, window=0,
             acc = acc * corr[..., None] + pv
             return (acc, new_mx, sm), None
 
-        acc0 = jnp.zeros((B, H, q_block, D), jnp.float32)
+        acc0 = jnp.zeros((B, H, q_block, Dv), jnp.float32)
         mx0 = jnp.full((B, H, q_block), NEG_INF, jnp.float32)
         sm0 = jnp.zeros((B, H, q_block), jnp.float32)
         (acc, mx, sm), _ = jax.lax.scan(
@@ -168,7 +183,7 @@ def _flash_forward(q, k, v, q_positions, k_positions, causal=True, window=0,
         q_step, None,
         (jnp.moveaxis(qb, 1, 0), jnp.moveaxis(qbpos, 1, 0)))
     out = jnp.moveaxis(outs, 0, 1)                           # [B,nq,H,qb,D]
-    out = jnp.moveaxis(out, 3, 2).reshape(B, Tp, H, D)
+    out = jnp.moveaxis(out, 3, 2).reshape(B, Tp, H, Dv)
     lse = jnp.moveaxis(lses, 0, 1)                           # [B,nq,H,qb]
     lse = jnp.moveaxis(lse, 3, 2).reshape(B, Tp, H)
     return out[:, :T], lse[:, :T]
@@ -403,3 +418,96 @@ def update_cache(k_cache, v_cache, k_new, v_new, position, ring: bool = False,
     k_cache = k_cache.at[bidx, seq_idx].set(k_new.astype(k_cache.dtype))
     v_cache = v_cache.at[bidx, seq_idx].set(v_new.astype(v_cache.dtype))
     return k_cache, v_cache
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def _mla_rope(cfg, x, positions):
+    """YaRN rotary positions over the roped part x [B, T, H, rope]."""
+    inv = yarn_inv_freq(cfg, cfg.qk_rope_head_dim)
+    scale = (cfg.yarn_mscale_of(cfg.yarn_mscale)
+             / cfg.yarn_mscale_of(cfg.yarn_mscale_all_dim))
+    return apply_rope_pairs(x, positions, inv, scale)
+
+
+def mla_latent(cfg, p, x, positions):
+    """The cached rows [B, T, r + rope] of tokens x [B, T, d]: the normed
+    latent, then the roped key part every head shares."""
+    r = cfg.kv_lora_rank
+    kv = x @ p["wkv_a"]
+    c = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+    k_pe = _mla_rope(cfg, kv[..., None, r:], positions)[:, :, 0]
+    return jnp.concatenate([c, k_pe.astype(c.dtype)], -1)
+
+
+def _mla_query(cfg, p, x, positions):
+    B, T, _ = x.shape
+    nope = cfg.qk_nope_head_dim
+    q = (x @ p["wq"]).reshape(B, T, cfg.n_heads, nope + cfg.qk_rope_head_dim)
+    return q[..., :nope], _mla_rope(cfg, q[..., nope:], positions)
+
+
+def _mla_up(cfg, p):
+    """W_UK [r, H, nope] and W_UV [r, H, v] of ``wkv_b``."""
+    w = p["wkv_b"].reshape(cfg.kv_lora_rank, cfg.n_heads,
+                           cfg.qk_nope_head_dim + cfg.v_head_dim)
+    return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+
+def mla_prefill(cfg, p, x, positions):
+    """Causal MLA over x [B, T, d] in the expanded per-head form. Returns
+    (out [B, T, H * v], cached rows [B, T, r + rope])."""
+    B, T, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    lat = mla_latent(cfg, p, x, positions)
+    q_nope, q_pe = _mla_query(cfg, p, x, positions)
+    kv = (lat[..., :r] @ p["wkv_b"]).reshape(
+        B, T, H, cfg.qk_nope_head_dim + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(lat[:, :, None, r:],
+                            (B, T, H, cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    k = jnp.concatenate([kv[..., :cfg.qk_nope_head_dim], k_pe], -1)
+    out, _ = _flash_forward(q, k, kv[..., cfg.qk_nope_head_dim:], positions,
+                            positions, causal=True,
+                            scale=cfg.mla_softmax_scale())
+    return out.reshape(B, T, H * cfg.v_head_dim), lat
+
+
+def mla_decode(cfg, p, x, positions, latent, slots, kv_len, spec=None):
+    """One decode step of MLA in the absorbed form: x [W, 1, d] at
+    positions [W, 1] of pool slots ``slots`` [W] of the latent pool
+    [P, S, r + rope]. Writes the step's rows, then reads the first
+    ``kv_len`` rows of each slot. Returns (out [W, 1, H * v], pool)."""
+    Wv = x.shape[0]
+    pos = positions[:, 0]
+    lat = mla_latent(cfg, p, x, positions)                   # [W, 1, Lw]
+    latent = latent.at[slots, pos].set(lat[:, 0].astype(latent.dtype))
+    q_nope, q_pe = _mla_query(cfg, p, x, positions)
+    w_uk, w_uv = _mla_up(cfg, p)
+    q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk,
+                       preferred_element_type=jnp.float32)
+    q = jnp.concatenate([q_lat, q_pe[:, 0].astype(jnp.float32)], -1)
+    kv_len = latent.shape[1] if kv_len is None else kv_len
+    o = latent_decode_attention(q, latent, slots, pos, kv_len,
+                                cfg.mla_softmax_scale(), cfg.kv_lora_rank,
+                                spec)
+    out = jnp.einsum("bhr,rhv->bhv", o.astype(x.dtype), w_uv,
+                     preferred_element_type=jnp.float32).astype(x.dtype)
+    return out.reshape(Wv, 1, cfg.n_heads * cfg.v_head_dim), latent
+
+
+def latent_decode_attention(q, latent, slots, position, kv_len: int,
+                            scale: float, v_dim: int,
+                            spec: Optional[registry.KernelSpec] = None):
+    """The latent mode of decode attention, spec-routed like
+    ``decode_attention``: the streaming ``decode_attn_latent`` kernel
+    (``backend="pallas"``), else the einsum oracle."""
+    spec = registry.resolve("decode_attn", spec, default=registry.REF)
+    if spec.backend == "pallas":
+        return pallas_latent_decode_attention(q, latent, slots, position,
+                                              kv_len, scale, v_dim,
+                                              spec=spec)
+    return ref_latent_decode_attention(q, latent, slots, position, kv_len,
+                                       scale, v_dim)

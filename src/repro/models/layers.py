@@ -1,6 +1,7 @@
 """Primitive layers: norms, activations, RoPE (standard + M-RoPE), MLP."""
 from __future__ import annotations
 
+import math
 from typing import Tuple
 
 import jax
@@ -82,3 +83,55 @@ def positional_rotate(x: jnp.ndarray, positions: jnp.ndarray, cfg
                                          (3,) + positions.shape)
         return apply_mrope(x, positions, cfg.rope_theta, cfg.mrope_sections)
     return apply_rope(x, positions, cfg.rope_theta)
+
+
+# ---------------------------------------------------------------------------
+# YaRN-scaled rotary positions over interleaved channel pairs (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+
+def yarn_correction_range(dim: int, theta: float, original_max: int,
+                          beta_fast: float, beta_slow: float
+                          ) -> Tuple[int, int]:
+    """The frequency indices (of ``dim // 2``) between which YaRN ramps
+    from the original to the interpolated frequencies: the dimensions
+    that turn ``beta_fast`` and ``beta_slow`` times over
+    ``original_max`` positions."""
+    def at(turns):
+        return (dim * math.log(original_max / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+    return (max(math.floor(at(beta_fast)), 0),
+            min(math.ceil(at(beta_slow)), dim - 1))
+
+
+def yarn_inv_freq(cfg, dim: int) -> jnp.ndarray:
+    """[dim // 2] rotary inverse frequencies: plain where
+    ``cfg.yarn_factor <= 1``, else YaRN's blend of the original and the
+    ``yarn_factor``-interpolated ones, by a linear ramp over the
+    correction range."""
+    extra = rope_freqs(dim, cfg.rope_theta)
+    if cfg.yarn_factor <= 1.0:
+        return extra
+    lo, hi = yarn_correction_range(dim, cfg.rope_theta,
+                                   cfg.yarn_original_max_position,
+                                   cfg.yarn_beta_fast, cfg.yarn_beta_slow)
+    hi = hi + 0.001 if hi == lo else hi
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - lo)
+                    / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp               # 1: keep the original frequency
+    return extra / cfg.yarn_factor * (1.0 - keep) + extra * keep
+
+
+def apply_rope_pairs(x: jnp.ndarray, positions: jnp.ndarray,
+                     inv_freq: jnp.ndarray, scale: float = 1.0
+                     ) -> jnp.ndarray:
+    """Rotary positions over the channel pairs ``(2i, 2i + 1)`` of
+    x [B, T, H, D], positions [B, T]; the output lays the pairs out as
+    halves ``[evens | odds]`` (DeepSeek-V2's ``apply_rotary_pos_emb``),
+    with cos and sin scaled by ``scale``."""
+    ang = positions[..., None].astype(jnp.float32) * inv_freq    # [B,T,D/2]
+    cos = (jnp.cos(ang) * scale)[:, :, None, :]
+    sin = (jnp.sin(ang) * scale)[:, :, None, :]
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.astype(x.dtype)

@@ -1,6 +1,19 @@
-"""Mixture-of-Experts FFN (dbrx: 16e top-4; phi3.5-moe: 16e top-2).
+"""Mixture-of-Experts FFN (dbrx: 16e top-4; phi3.5-moe: 16e top-2;
+DeepSeek-V2-Lite: 64e top-6 with 2 shared experts).
 
-Sort-based capacity dispatch (MegaBlocks-lite, static shapes):
+Serving (prefill and decode) is dropless, ``moe_dropless``: every
+(token, expert) assignment is computed, so a token's output never depends
+on what else is in its batch, and a prompt padded at its tail routes its
+pads without touching a real token. The assignments are sorted by expert
+and the three expert matmuls run as grouped matmuls over the experts'
+group sizes: the TPU's megablox ``gmm`` Pallas kernel on an accelerator
+(its device operations are named ``gmm``), ``lax.ragged_dot`` on a CPU
+host. The matmuls read only the experts that received tokens, from the
+layer's expert weights that the caller's layer scan slices out of the
+stack (on a TPU that slice is a copy of all of the layer's experts).
+
+Training keeps ``moe_ffn``, sort-based capacity dispatch (MegaBlocks-lite,
+static shapes):
   router top-k -> flatten (token, expert) assignments -> stable-sort by
   expert -> slot-in-expert via segment arithmetic -> scatter into a dense
   [E, C, d] buffer -> batched expert GEMMs -> weighted scatter-add combine.
@@ -9,6 +22,12 @@ This keeps compute proportional to top_k (not E) and avoids the GShard
 [N, E, C] one-hot dispatch tensor, which does not fit at train_4k scale.
 Tokens beyond expert capacity are dropped (standard GShard semantics); the
 residual path keeps their representation intact.
+
+``route_topk`` takes float32 logits and a softmax over all experts; the
+chosen experts' probabilities are renormalised over the chosen ones where
+``norm`` (Mixtral-style, the default) and used as they are, times the
+config's ``routed_scaling_factor``, where not (DeepSeek's
+``norm_topk_prob`` false).
 
 Sharding (DESIGN.md §7): experts over the "data" axis (EP), expert-internal
 f over "model" (TP); the dispatch scatter becomes an all-to-all under GSPMD.
@@ -19,15 +38,21 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+from repro.kernels import registry
 
 
-def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int
-               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x [N, d], router_w [d, E] -> (weights [N, k] softmaxed over chosen,
-    expert_ids [N, k])."""
-    logits = (x.astype(jnp.float32) @ router_w.astype(jnp.float32))
-    top_logits, top_ids = jax.lax.top_k(logits, top_k)
-    w = jax.nn.softmax(top_logits, axis=-1)
+def route_topk(x: jnp.ndarray, router_w: jnp.ndarray, top_k: int,
+               norm: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """x [N, d], router_w [d, E] -> (weights [N, k], expert_ids [N, k]):
+    the top-k of the softmax over every expert's float32 logit, the
+    weights renormalised over the chosen experts where ``norm``."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    w, top_ids = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if norm:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
     return w, top_ids
 
 
@@ -90,3 +115,75 @@ def moe_ffn(
     y_tok = y_e[e_idx, s_idx]                                # [N*k, d]
     y_tok = jnp.where(keep[:, None], y_tok, 0.0) * sw[:, None].astype(y_e.dtype)
     return jnp.zeros((N, d), y_e.dtype).at[st].add(y_tok)
+
+
+# ---------------------------------------------------------------------------
+# dropless serving path
+# ---------------------------------------------------------------------------
+
+#: rows of one m-tile of the grouped matmul, at most
+GMM_MAX_TM = 256
+
+
+def _gmm_tiles(m: int) -> Tuple[int, int]:
+    """(padded rows, m-tile): one tile of up to ``GMM_MAX_TM`` rows (a
+    decode wave's assignments), so each touched expert's weights stream
+    once; tiles of ``GMM_MAX_TM`` beyond."""
+    if m <= GMM_MAX_TM:
+        tm = max(16, -(-m // 16) * 16)
+        return tm, tm
+    return -(-m // GMM_MAX_TM) * GMM_MAX_TM, GMM_MAX_TM
+
+
+def grouped_matmul(x: jnp.ndarray, w: jnp.ndarray, sizes: jnp.ndarray,
+                   tiling: Tuple[int, int, int]) -> jnp.ndarray:
+    """``x[rows of group e] @ w[e]`` for every group e, float32 out. x [m,
+    k] holds the groups' rows in order (``sizes`` [E] of them, the rest
+    unread and their output rows undefined), w [E, k, n]. The megablox
+    ``gmm`` kernel on an accelerator, ``lax.ragged_dot`` on a CPU host."""
+    if registry.on_cpu():
+        return jax.lax.ragged_dot(x, w, sizes,
+                                  preferred_element_type=jnp.float32)
+    return gmm(x, w, sizes, preferred_element_type=jnp.float32,
+               tiling=tiling)
+
+
+def moe_dropless(x: jnp.ndarray, router_w: jnp.ndarray, w_gate: jnp.ndarray,
+                 w_up: jnp.ndarray, w_down: jnp.ndarray, top_k: int,
+                 norm: bool = True, scale: float = 1.0,
+                 act: str = "silu") -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Routed experts of tokens x [N, d], every assignment computed, with
+    expert weights w_gate, w_up [E, d, f] and w_down [E, f, d]. Returns
+    (out [N, d] float32, stats int32 [3]): the assignments, the experts
+    that received any, and the largest expert's load."""
+    N, d = x.shape
+    E, _, f = w_gate.shape
+    w, ids = route_topk(x, router_w, top_k, norm)
+    if not norm:
+        w = w * scale
+    flat_e = ids.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    tok = jnp.repeat(jnp.arange(N), top_k)[order]
+    sizes = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    M = N * top_k
+    Mp, tm = _gmm_tiles(M)
+    xs = jnp.pad(x[tok], ((0, Mp - M), (0, 0)))
+    g = grouped_matmul(xs, w_gate, sizes, (tm, min(d, 512), f))
+    u = grouped_matmul(xs, w_up, sizes, (tm, min(d, 512), f))
+    g = jax.nn.silu(g) if act == "silu" else jax.nn.gelu(g)
+    y = grouped_matmul((g * u).astype(x.dtype), w_down, sizes,
+                       (tm, f, min(d, 512)))[:M]
+    y = y * w.reshape(-1)[order][:, None]
+    out = jnp.zeros((N, d), jnp.float32).at[tok].add(y)
+    stats = jnp.stack([jnp.int32(M), jnp.sum(sizes > 0, dtype=jnp.int32),
+                       jnp.max(sizes)])
+    return out, stats
+
+
+def add_stats(a, b):
+    """Routing counters of two layers (or waves) together: summed
+    assignments and experts touched, the larger largest load; ``None``
+    (no MoE layer) adds nothing."""
+    if a is None or b is None:
+        return b if a is None else a
+    return jnp.stack([a[0] + b[0], a[1] + b[1], jnp.maximum(a[2], b[2])])

@@ -10,7 +10,17 @@ execution modes:
 Compile economy (DESIGN.md §8): layers are stacked per layer-class and the
 stack is applied by a ``lax.scan`` over groups of ``period`` layers, so HLO
 size is O(period), independent of depth — required to compile llama3-405b's
-126 layers on one host core with 512 fake devices.
+126 layers on one host core with 512 fake devices. An MoE model's leading
+dense layers (``first_k_dense``) are the class ``LEAD``, applied before
+the scan.
+
+With multi-head latent attention (``cfg.mla``) every attention class
+caches one latent row per position (``latent`` [n, B, S, r + rope]) in
+place of K and V. MoE layers run dropless outside training
+(``moe.moe_dropless``), with the shared experts as one SwiGLU over every
+token; the stack sums their routing counters (assignments, experts
+touched, largest load) for a decode wave (``decode_wave(...,
+return_stats=True)``).
 """
 from __future__ import annotations
 
@@ -22,8 +32,9 @@ import jax.numpy as jnp
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.attention import (decode_attention, flash_attention,
-                                    prefill_cache, update_cache)
-from repro.models.config import ModelConfig
+                                    mla_decode, mla_prefill, prefill_cache,
+                                    update_cache)
+from repro.models.config import LEAD, ModelConfig
 from repro.models.ctx import constrain
 from repro.models.layers import positional_rotate, rms_norm, swiglu
 
@@ -38,8 +49,9 @@ def _dense_init(key, shape, scale=0.02, dtype=jnp.bfloat16):
     return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
 
 
-def _init_block_class(key, cfg: ModelConfig, n: int, cross: bool) -> Params:
-    """Stacked params for `n` layers of one class."""
+def _init_block_class(key, cfg: ModelConfig, n: int, cross: bool,
+                      cls: str = "global") -> Params:
+    """Stacked params for `n` layers of class ``cls``."""
     d, f = cfg.d_model, cfg.d_ff
     H, KV, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     dt = jnp.dtype(cfg.dtype)
@@ -63,21 +75,36 @@ def _init_block_class(key, cfg: ModelConfig, n: int, cross: bool) -> Params:
         return p
 
     p["ln1"] = jnp.ones((n, d), dt)
-    p["wq"] = _dense_init(next(ks), (n, d, H * dh), dtype=dt)
-    p["wk"] = _dense_init(next(ks), (n, d, KV * dh), dtype=dt)
-    p["wv"] = _dense_init(next(ks), (n, d, KV * dh), dtype=dt)
-    p["wo"] = _dense_init(next(ks), (n, H * dh, d), dtype=dt)
+    if cfg.mla:
+        r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+        p["wq"] = _dense_init(next(ks), (n, d, H * (nope + cfg.qk_rope_head_dim)),
+                              dtype=dt)
+        p["wkv_a"] = _dense_init(next(ks), (n, d, cfg.latent_width), dtype=dt)
+        p["kv_norm"] = jnp.ones((n, r), dt)
+        p["wkv_b"] = _dense_init(next(ks), (n, r, H * (nope + cfg.v_head_dim)),
+                                 dtype=dt)
+        p["wo"] = _dense_init(next(ks), (n, H * cfg.v_head_dim, d), dtype=dt)
+    else:
+        p["wq"] = _dense_init(next(ks), (n, d, H * dh), dtype=dt)
+        p["wk"] = _dense_init(next(ks), (n, d, KV * dh), dtype=dt)
+        p["wv"] = _dense_init(next(ks), (n, d, KV * dh), dtype=dt)
+        p["wo"] = _dense_init(next(ks), (n, H * dh, d), dtype=dt)
     if cfg.qkv_bias:
         p["bq"] = jnp.zeros((n, H * dh), dt)
         p["bk"] = jnp.zeros((n, KV * dh), dt)
         p["bv"] = jnp.zeros((n, KV * dh), dt)
     p["ln2"] = jnp.ones((n, d), dt)
-    if cfg.block == "moe":
-        E = cfg.n_experts
+    if cfg.is_moe_class(cls):
+        E, fe = cfg.n_experts, cfg.expert_width
         p["router"] = _dense_init(next(ks), (n, d, E), dtype=jnp.float32)
-        p["wg"] = _dense_init(next(ks), (n, E, d, f), dtype=dt)
-        p["wu"] = _dense_init(next(ks), (n, E, d, f), dtype=dt)
-        p["wd"] = _dense_init(next(ks), (n, E, f, d), dtype=dt)
+        p["wg"] = _dense_init(next(ks), (n, E, d, fe), dtype=dt)
+        p["wu"] = _dense_init(next(ks), (n, E, d, fe), dtype=dt)
+        p["wd"] = _dense_init(next(ks), (n, E, fe, d), dtype=dt)
+        if cfg.n_shared_experts:
+            fs = cfg.n_shared_experts * fe
+            p["ws_g"] = _dense_init(next(ks), (n, d, fs), dtype=dt)
+            p["ws_u"] = _dense_init(next(ks), (n, d, fs), dtype=dt)
+            p["ws_d"] = _dense_init(next(ks), (n, fs, d), dtype=dt)
     else:
         p["wg"] = _dense_init(next(ks), (n, d, f), dtype=dt)
         p["wu"] = _dense_init(next(ks), (n, d, f), dtype=dt)
@@ -119,7 +146,8 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
     for i, cls in enumerate(cfg.pattern_classes()):
         n = len(cfg.class_layers(cls))
         classes[cls] = _init_block_class(
-            jax.random.fold_in(k_cls, i), cfg, n, cross=(cfg.arch == "encdec"))
+            jax.random.fold_in(k_cls, i), cfg, n, cross=(cfg.arch == "encdec"),
+            cls=cls)
     params["classes"] = classes
     if cfg.arch == "encdec":
         enc_cfg = ModelConfig(
@@ -155,6 +183,8 @@ def init_cache(cfg: ModelConfig, B: int, max_seq: int,
             c["wkv"] = jnp.zeros((n, B, H, dh, dh), jnp.float32)
             c["st"] = jnp.zeros((n, B, d), dt)
             c["sc"] = jnp.zeros((n, B, d), dt)
+        elif cfg.mla:
+            c["latent"] = jnp.zeros((n, B, max_seq, cfg.latent_width), dt)
         else:
             S = cfg.window if (cls == "local" and cfg.window > 0) else max_seq
             c["k"] = jnp.zeros((n, B, S, KV, dh), dt)
@@ -206,6 +236,9 @@ def _self_attention(cfg, p, h, positions, mode, cache, window, slots=None,
     the math). Ring caches are window-sized already and are never
     cropped. ``attn_spec`` picks the decode-attention kernel flavor."""
     B, T, _ = h.shape
+    if cfg.mla:
+        return _latent_attention(cfg, p, h, positions, mode, cache, slots,
+                                 kv_len, attn_spec)
     q, k, v = _proj_qkv(cfg, p, h)
     pos1d = positions[0] if positions.ndim == 3 else positions
     q = positional_rotate(q, positions, cfg)
@@ -237,6 +270,26 @@ def _self_attention(cfg, p, h, positions, mode, cache, window, slots=None,
     return out @ p["wo"], new_cache
 
 
+def _latent_attention(cfg, p, h, positions, mode, cache, slots, kv_len,
+                      attn_spec):
+    """``_self_attention`` of an MLA layer over its ``latent`` cache."""
+    new_cache = cache
+    if mode == "decode":
+        if slots is None:
+            slots = jnp.arange(h.shape[0], dtype=jnp.int32)
+        out, lat = mla_decode(cfg, p, h, positions, cache["latent"], slots,
+                              kv_len, attn_spec)
+        new_cache = dict(cache, latent=lat)
+    else:
+        out, lat = mla_prefill(cfg, p, h, positions)
+        if mode == "prefill":
+            S = cache["latent"].shape[1]
+            lat = lat[:, :S].astype(cache["latent"].dtype)
+            new_cache = dict(cache, latent=jnp.pad(
+                lat, ((0, 0), (0, S - lat.shape[1]), (0, 0))))
+    return out @ p["wo"], new_cache
+
+
 def _cross_attention(cfg, p, h, enc_states, mode, cache, slots=None):
     """Decoder cross-attention over encoder states (RETRO/EncDec path)."""
     B, T, _ = h.shape
@@ -262,21 +315,34 @@ def _cross_attention(cfg, p, h, enc_states, mode, cache, slots=None):
     return h + out @ p["xwo"], new_cache
 
 
-def _ffn(cfg, p, x):
-    if cfg.block == "moe":
-        B, T, d = x.shape
-        flat = x.reshape(B * T, d)
+def _ffn(cfg, p, x, mode, moe: bool):
+    """The layer's MLP. Returns (out, routing stats or None): an MoE layer
+    runs dropless outside training and capacity-bounded in it."""
+    if not moe:
+        return swiglu(x, p["wg"], p["wu"], p["wd"], cfg.act), None
+    B, T, d = x.shape
+    flat = x.reshape(B * T, d)
+    stats = None
+    if mode == "train":
         out = moe_lib.moe_ffn(flat, p["router"], p["wg"], p["wu"], p["wd"],
                               cfg.top_k, act=cfg.act)
-        return out.reshape(B, T, d).astype(x.dtype)
-    return swiglu(x, p["wg"], p["wu"], p["wd"], cfg.act)
+    else:
+        out, stats = moe_lib.moe_dropless(
+            flat, p["router"], p["wg"], p["wu"], p["wd"], cfg.top_k,
+            norm=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor, act=cfg.act)
+    out = out.reshape(B, T, d).astype(x.dtype)
+    if "ws_g" in p:
+        out = out + swiglu(x, p["ws_g"], p["ws_u"], p["ws_d"], cfg.act)
+    return out, stats
 
 
 def apply_block(cfg: ModelConfig, p: Params, h: jnp.ndarray,
                 positions: jnp.ndarray, mode: str, cache: Optional[Params],
                 window: int, enc_states=None, slots=None, kv_len=None,
-                attn_spec=None):
-    """One layer. Returns (h, new_cache).
+                attn_spec=None, cls: str = "global"):
+    """One layer of class ``cls``. Returns (h, new_cache, MoE routing
+    stats or None).
 
     With ``slots`` (batched-slot decode over a KV-cache pool) the cache
     leaves keep their pooled batch dim ``P``; recurrent states (SSM /
@@ -301,12 +367,12 @@ def apply_block(cfg: ModelConfig, p: Params, h: jnp.ndarray,
             rp, rms_norm(h, p["ln2"], cfg.norm_eps), st.shift_c)
         h = h + y2
         if cache is None:
-            return h, None
+            return h, None, None
         rows = dict(wkv=wkv, st=sh_t, sc=sh_c)
         if slots is None:
-            return h, rows
+            return h, rows, None
         return h, {key: cache[key].at[slots].set(
-            rows[key].astype(cache[key].dtype)) for key in rows}
+            rows[key].astype(cache[key].dtype)) for key in rows}, None
 
     hn = rms_norm(h, p["ln1"], cfg.norm_eps)
     attn_out, new_cache = _self_attention(cfg, p, hn, positions, mode,
@@ -339,8 +405,10 @@ def apply_block(cfg: ModelConfig, p: Params, h: jnp.ndarray,
     if enc_states is not None and "xwq" in p:
         h, new_cache = _cross_attention(cfg, p, h, enc_states, mode,
                                         new_cache, slots=slots)
-    h = h + _ffn(cfg, p, rms_norm(h, p["ln2"], cfg.norm_eps))
-    return h, new_cache
+    out, stats = _ffn(cfg, p, rms_norm(h, p["ln2"], cfg.norm_eps), mode,
+                      cfg.is_moe_class(cls))
+    return h + out, new_cache, stats
+
 
 
 # ---------------------------------------------------------------------------
@@ -351,9 +419,11 @@ def apply_stack(cfg: ModelConfig, classes_params: Params, h: jnp.ndarray,
                 positions: jnp.ndarray, mode: str,
                 caches: Optional[Params] = None, enc_states=None,
                 remat: bool = False, slots=None, kv_len=None,
-                attn_spec=None) -> Tuple[jnp.ndarray, Optional[Params]]:
-    """Apply all n_layers in order. Layers are grouped by the static
-    ``layer_pattern`` cycle; a lax.scan over whole cycles keeps HLO small.
+                attn_spec=None) -> Tuple[jnp.ndarray, Optional[Params], Any]:
+    """Apply all n_layers in order: the ``LEAD`` layers (``first_k_dense``)
+    one by one, then the static ``layer_pattern`` cycle, a lax.scan over
+    whole cycles keeping HLO small. Returns (h, caches, the MoE layers'
+    summed routing stats or None).
 
     ``slots`` (decode only): the caches are a KV-cache pool of ``P`` slot
     rows while ``h`` is one wave of ``W`` active rows — see
@@ -362,7 +432,7 @@ def apply_stack(cfg: ModelConfig, classes_params: Params, h: jnp.ndarray,
     ``_self_attention``) applied uniformly to every full-cache layer."""
     pattern = cfg.layer_pattern
     period = len(pattern)
-    n_full, tail = divmod(cfg.n_layers, period)
+    n_full, tail = divmod(cfg.n_layers - cfg.first_k_dense, period)
     # per pattern-slot: (class name, #layers of that class per full cycle,
     #                    offset of this slot within the cycle's class layers)
     cnt = {c: pattern.count(c) for c in set(pattern)}
@@ -372,56 +442,52 @@ def apply_stack(cfg: ModelConfig, classes_params: Params, h: jnp.ndarray,
         off.append(seen.get(c, 0))
         seen[c] = seen.get(c, 0) + 1
 
-    def layer_at(params_c, idx):
-        return jax.tree.map(lambda a: a[idx], params_c)
+    def one_layer(h, caches_, stats, cls, idx):
+        p = jax.tree.map(lambda a: a[idx], classes_params[cls])
+        window = cfg.window if cls == "local" else 0
+        cache = (jax.tree.map(lambda a: a[idx], caches_["classes"][cls])
+                 if caches_ is not None else None)
+        h, new_cache, st = apply_block(cfg, p, h, positions, mode, cache,
+                                       window, enc_states, slots=slots,
+                                       kv_len=kv_len, attn_spec=attn_spec,
+                                       cls=cls)
+        if caches_ is not None:
+            upd = jax.tree.map(
+                lambda a, nc: jax.lax.dynamic_update_index_in_dim(
+                    a, nc.astype(a.dtype), idx, 0),
+                caches_["classes"][cls], new_cache)
+            caches_ = dict(caches_,
+                           classes=dict(caches_["classes"], **{cls: upd}))
+        return h, caches_, moe_lib.add_stats(stats, st)
+
+    stats = None
+    for idx in range(cfg.first_k_dense):
+        h, caches, stats = one_layer(h, caches, stats, LEAD, idx)
 
     def apply_cycle(carry, g):
-        h, caches_ = carry
+        h, caches_, stats_ = carry
         # pin activations to batch-over-dp: without this hint the SPMD
         # partitioner follows the FSDP weight sharding and replicates the
         # batch while splitting d — measured 34 TB/layer of activation
         # traffic for llama3-405b bwd (EXPERIMENTS.md §Perf iteration 5)
         h = constrain(h, "dp", None, None)
         for s, cls in enumerate(pattern):
-            idx = g * cnt[cls] + off[s]
-            p = layer_at(classes_params[cls], idx)
-            window = cfg.window if cls == "local" else 0
-            cache = (jax.tree.map(lambda a: a[idx], caches_["classes"][cls])
-                     if caches_ is not None else None)
-            h, new_cache = apply_block(cfg, p, h, positions, mode, cache,
-                                       window, enc_states, slots=slots,
-                                       kv_len=kv_len, attn_spec=attn_spec)
-            if caches_ is not None:
-                upd = jax.tree.map(
-                    lambda a, nc: jax.lax.dynamic_update_index_in_dim(
-                        a, nc.astype(a.dtype), idx, 0),
-                    caches_["classes"][cls], new_cache)
-                caches_ = dict(caches_,
-                               classes=dict(caches_["classes"], **{cls: upd}))
-        return (h, caches_), None
+            h, caches_, stats_ = one_layer(h, caches_, stats_, cls,
+                                           g * cnt[cls] + off[s])
+        return (h, caches_, stats_), None
 
+    if n_full > 0 and cfg.is_moe_class(pattern[0]) and stats is None:
+        # the scan carries the stats from its first cycle on
+        stats = jnp.zeros((3,), jnp.int32) if mode != "train" else None
     body = jax.checkpoint(apply_cycle) if remat else apply_cycle
     if n_full > 0:
-        (h, caches), _ = jax.lax.scan(body, (h, caches),
-                                      jnp.arange(n_full))
+        (h, caches, stats), _ = jax.lax.scan(body, (h, caches, stats),
+                                             jnp.arange(n_full))
     for t in range(tail):  # remainder layers, unrolled (< period of them)
         cls = pattern[t]
-        idx = n_full * cnt[cls] + off[t]
-        p = layer_at(classes_params[cls], idx)
-        window = cfg.window if cls == "local" else 0
-        cache = (jax.tree.map(lambda a: a[idx], caches["classes"][cls])
-                 if caches is not None else None)
-        h, new_cache = apply_block(cfg, p, h, positions, mode, cache, window,
-                                   enc_states, slots=slots, kv_len=kv_len,
-                                   attn_spec=attn_spec)
-        if caches is not None:
-            upd = jax.tree.map(
-                lambda a, nc: jax.lax.dynamic_update_index_in_dim(
-                    a, nc.astype(a.dtype), idx, 0),
-                caches["classes"][cls], new_cache)
-            caches = dict(caches, classes=dict(caches["classes"],
-                                               **{cls: upd}))
-    return h, caches
+        h, caches, stats = one_layer(h, caches, stats, cls,
+                                     n_full * cnt[cls] + off[t])
+    return h, caches, stats
 
 
 # ---------------------------------------------------------------------------
@@ -482,22 +548,23 @@ def forward(params: Params, cfg: ModelConfig,
             caches: Optional[Params] = None,
             enc_states: Optional[jnp.ndarray] = None,
             remat: bool = False, return_hidden: bool = False, slots=None,
-            kv_len=None, attn_spec=None):
+            kv_len=None, attn_spec=None, return_stats: bool = False):
     """Full forward. Provide `tokens` [B,T] or `embeds` [B,T,d] (modality
-    stubs). Returns (logits [B,T,V], caches[, hidden])."""
+    stubs). Returns (logits [B,T,V], caches[, hidden][, MoE routing
+    stats])."""
     h = embed_tokens(params, tokens) if embeds is None else embeds
     h = constrain(h, "dp", None, None)
     B, T = h.shape[:2]
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-    h, caches = apply_stack(cfg, params["classes"], h, positions, mode,
-                            caches, enc_states, remat=remat, slots=slots,
-                            kv_len=kv_len, attn_spec=attn_spec)
+    h, caches, stats = apply_stack(cfg, params["classes"], h, positions,
+                                   mode, caches, enc_states, remat=remat,
+                                   slots=slots, kv_len=kv_len,
+                                   attn_spec=attn_spec)
     h = constrain(h, "dp", None, None)
     logits = unembed(params, cfg, h)
-    if return_hidden:
-        return logits, caches, h
-    return logits, caches
+    out = (logits, caches) + ((h,) if return_hidden else ())
+    return out + ((stats,) if return_stats else ())
 
 
 def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray
@@ -509,8 +576,8 @@ def hidden_states(params: Params, cfg: ModelConfig, tokens: jnp.ndarray
     h = embed_tokens(params, tokens)
     B, T = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(T)[None], (B, T))
-    h, _ = apply_stack(cfg, params["classes"], h, positions, "train", None,
-                       None)
+    h, _, _ = apply_stack(cfg, params["classes"], h, positions, "train",
+                          None, None)
     return h
 
 
@@ -527,8 +594,8 @@ def prefill(params: Params, cfg: ModelConfig, caches: Params,
     if cfg.rope_mode == "mrope":
         pos = jnp.broadcast_to(pos[None], (3, B, T))
     h = constrain(embed_tokens(params, tokens), "dp", None, None)
-    h, caches = apply_stack(cfg, params["classes"], h, pos, "prefill",
-                            caches, enc_states)
+    h, caches, _ = apply_stack(cfg, params["classes"], h, pos, "prefill",
+                               caches, enc_states)
     h = constrain(h, "dp", None, None)
     last = jax.lax.dynamic_index_in_dim(h, length - 1, axis=1,
                                         keepdims=False)
@@ -560,7 +627,8 @@ def decode_wave(params: Params, cfg: ModelConfig, caches: Params,
                 token: jnp.ndarray, slots: jnp.ndarray,
                 position: jnp.ndarray,
                 enc_states: Optional[jnp.ndarray] = None,
-                return_hidden: bool = False, kv_len=None, attn_spec=None):
+                return_hidden: bool = False, kv_len=None, attn_spec=None,
+                return_stats: bool = False):
     """One serving step for a whole wave over a slotted KV-cache pool.
 
     ``caches`` hold ``P`` pooled slot rows (built with
@@ -574,7 +642,9 @@ def decode_wave(params: Params, cfg: ModelConfig, caches: Params,
     decode-attention kernel (grouped ref / streaming Pallas / legacy
     einsum oracle) — see ``models/attention.decode_attention``.
 
-    Returns (logits [W,V], new pool caches[, hidden [W,d]]). One call =
+    Returns (logits [W,V], new pool caches[, hidden [W,d]][, the MoE
+    layers' routing stats: int32 [assignments, experts touched, largest
+    load], summed over layers, or None]). One call =
     one LM dispatch for every active sequence, regardless of how many
     requests the wave spans — the ChamLM analogue of the retrieval
     service's coalesced batch (paper §5 batched GPU pool)."""
@@ -585,12 +655,11 @@ def decode_wave(params: Params, cfg: ModelConfig, caches: Params,
     out = forward(params, cfg, tokens=token, positions=pos, mode="decode",
                   caches=caches, enc_states=enc_states, slots=slots,
                   return_hidden=return_hidden, kv_len=kv_len,
-                  attn_spec=attn_spec)
+                  attn_spec=attn_spec, return_stats=return_stats)
+    logits, caches = out[0][:, 0], out[1]
     if return_hidden:
-        logits, caches, h = out
-        return logits[:, 0], caches, h[:, 0]
-    logits, caches = out
-    return logits[:, 0], caches
+        return (logits, caches, out[2][:, 0]) + out[3:]
+    return (logits, caches) + out[2:]
 
 
 def lm_loss(params: Params, cfg: ModelConfig, batch: Dict[str, jnp.ndarray],

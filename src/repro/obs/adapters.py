@@ -26,8 +26,8 @@ _STAGES = ("queue_wait", "scan", "merge", "gather")
 
 def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
     """Register collectors for everything an ``RalmEngine`` owns: KV
-    pool, admission prefill, retrieval service, kernel-registry
-    fallbacks. Idempotent metric creation; call once per (registry,
+    pool, admission prefill, an MoE model's routing counters, retrieval
+    service, kernel-registry fallbacks. Idempotent metric creation; call once per (registry,
     engine) pair."""
     kv_slots = registry.gauge(
         "ralm_kv_slots", "KV-pool slot rows by state")
@@ -54,6 +54,18 @@ def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
         "ralm_prefill_tokens_total",
         "prefill positions by kind (prompt, or tail padding up to the "
         "prompt-length bucket)")
+    moe_assign = registry.counter(
+        "ralm_moe_assignments_total",
+        "MoE decode waves: (token, expert) assignments the expert "
+        "matmuls computed, summed over layers and waves")
+    moe_touched = registry.counter(
+        "ralm_moe_experts_touched_total",
+        "MoE decode waves: distinct experts that received a token, "
+        "summed over layers and waves (expert weights read)")
+    moe_load = registry.gauge(
+        "ralm_moe_max_expert_load",
+        "MoE decode waves: the largest one expert's assignments in a "
+        "layer of a wave")
     fallbacks = registry.counter(
         "ralm_kernel_fallbacks_total",
         "pallas->ref kernel routing decisions, by op")
@@ -132,6 +144,11 @@ def bind_engine_metrics(registry: MetricsRegistry, engine) -> None:
         prefill_programs.set(len(pf.programs))
         prefill_tokens.set_total(pf.prompt_tokens, labels={"kind": "prompt"})
         prefill_tokens.set_total(pf.pad_tokens, labels={"kind": "pad"})
+        moe = engine.moe_stats() if hasattr(engine, "moe_stats") else None
+        if moe is not None:
+            moe_assign.set_total(moe["assignments"])
+            moe_touched.set_total(moe["experts_touched"])
+            moe_load.set(moe["max_load"])
         from repro.kernels import registry as kreg
         for op, n in kreg.fallback_counts().items():
             fallbacks.set_total(n, labels={"op": op})

@@ -54,6 +54,7 @@ from repro.core.chamvs import ChamVSConfig
 from repro.core.ivfpq import IVFPQParams, IVFPQShard
 from repro.core.rag import RagConfig
 from repro.launch.mesh import make_mesh_for
+from repro.models import moe as moe_lib
 from repro.models import transformer as tf
 from repro.models.config import ModelConfig
 from repro.obs.trace import NULL_TRACER, Tracer
@@ -98,14 +99,15 @@ class PrefillStats:
 def pads_unread(cfg: ModelConfig) -> bool:
     """Whether a prompt padded at its tail prefills its real rows and
     cache entries as the exact prompt does, with no pad entry ever read.
-    True when every cache class is a full-length KV cache (dense blocks,
-    no sliding-window ring): causal attention keeps the pads out of
-    every real row, and their K/V land at positions >= the prompt
-    length, which decode overwrites before it reads them. A ring would
-    wrap pad K/V over real positions, a recurrent state (``rwkv6``,
-    ``hybrid``) would carry the pads forward, and MoE capacity would let
-    pads displace real tokens."""
-    return cfg.block == "dense" and not (
+    True when every cache class is a full-length KV or latent cache
+    (dense or MoE blocks, no sliding-window ring): causal attention keeps
+    the pads out of every real row, and their K/V land at positions >=
+    the prompt length, which decode overwrites before it reads them;
+    serving MoE is dropless, so a pad's experts never touch a real
+    token. A ring would wrap pad K/V over real positions, and a
+    recurrent state (``rwkv6``, ``hybrid``) would carry the pads
+    forward."""
+    return cfg.block in ("dense", "moe") and not (
         cfg.window > 0 and "local" in cfg.pattern_classes())
 
 
@@ -163,17 +165,32 @@ def _jit_decode(params, cfg: ModelConfig, caches, token, position,
 @functools.partial(jax.jit, static_argnums=1, donate_argnums=(2,),
                    static_argnames=("kv_len", "attn_spec"))
 def _jit_decode_wave(params, cfg: ModelConfig, caches, token, slots,
-                     position, enc_states, *, kv_len=None, attn_spec=None):
+                     position, enc_states, totals=None, *, kv_len=None,
+                     attn_spec=None):
     """One dispatch per wave over the slotted KV-cache pool. The pool
     caches are donated: the per-layer K/V writes land in place, so step
     cost is O(wave), not O(pool). Shared jit cache across engines, keyed
     on (cfg, wave bucket, pool shape, kv_len, attn_spec) — ``kv_len`` is
     the wave's block-aligned valid prefix (attention reads crop to it,
     see ``KVCachePool.attn_len``), ``attn_spec`` the static
-    decode-attention kernel selection."""
-    return tf.decode_wave(params, cfg, caches, token, slots, position,
-                          enc_states=enc_states, return_hidden=True,
-                          kv_len=kv_len, attn_spec=attn_spec)
+    decode-attention kernel selection. An MoE model passes its running
+    routing ``totals`` (int32 [3]: assignments, experts touched, largest
+    load) and gets them back, fourth, with the wave's added on the
+    device: no host sync. A dense model passes ``None`` and its program
+    computes no counter."""
+    out = tf.decode_wave(params, cfg, caches, token, slots, position,
+                         enc_states=enc_states, return_hidden=True,
+                         kv_len=kv_len, attn_spec=attn_spec,
+                         return_stats=totals is not None)
+    if totals is None:
+        return out
+    return out[:3] + (moe_lib.add_stats(totals, out[3]),)
+
+
+def _moe_totals(cfg: ModelConfig) -> Optional[jnp.ndarray]:
+    """The routing counters a backend carries through its waves: zeros
+    for an MoE model, ``None`` (no counter, no work) for a dense one."""
+    return jnp.zeros((3,), jnp.int32) if cfg.block == "moe" else None
 
 
 class MonolithicBackend:
@@ -186,6 +203,7 @@ class MonolithicBackend:
     def __init__(self, params, cfg: ModelConfig):
         self.params, self.cfg = params, cfg
         self.decode_dispatches = 0      # LM dispatch counter (tests/bench)
+        self.moe_totals = _moe_totals(cfg)
 
     def prefill(self, rag: RagConfig, prompt, length: int, max_seq: int):
         """Prefill ``prompt`` [B, T] whose first ``length`` positions are
@@ -204,9 +222,12 @@ class MonolithicBackend:
                     kv_len=None, attn_spec=None):
         """Advance one wave of pooled slots: token/slots/position [W]."""
         self.decode_dispatches += 1
-        return _jit_decode_wave(self.params, self.cfg, caches, token,
-                                slots, position, enc_states,
-                                kv_len=kv_len, attn_spec=attn_spec)
+        out = _jit_decode_wave(self.params, self.cfg, caches, token, slots,
+                               position, enc_states, self.moe_totals,
+                               kv_len=kv_len, attn_spec=attn_spec)
+        if self.moe_totals is not None:
+            self.moe_totals = out[3]
+        return out[:3]
 
     def encode_chunks(self, chunks: jnp.ndarray) -> jnp.ndarray:
         """RETRO re-encode of retrieved chunk tokens [B, L] — LM-side
@@ -235,6 +256,7 @@ class DisaggregatedBackend:
             lm_devices, ret_devices, len(devs))
         self.params, self.cfg = params, cfg
         self.decode_dispatches = 0
+        self.moe_totals = _moe_totals(cfg)
         self.times = PoolTimes() if measure else None
         # LM pool: pure data-parallel decode (each unit = one "GPU process")
         self.lm_mesh = make_mesh_for(devs[:lm_devices], data=lm_devices)
@@ -268,9 +290,13 @@ class DisaggregatedBackend:
         self.decode_dispatches += 1
         t0 = time.time()
         with use_mesh(self.lm_mesh):
-            logits, caches, hidden = _jit_decode_wave(
+            out = _jit_decode_wave(
                 self.params, self.cfg, caches, token, slots, position,
-                enc_states, kv_len=kv_len, attn_spec=attn_spec)
+                enc_states, self.moe_totals, kv_len=kv_len,
+                attn_spec=attn_spec)
+        logits, caches, hidden = out[:3]
+        if self.moe_totals is not None:
+            self.moe_totals = out[3]
         if self.times is not None:
             logits.block_until_ready()
             self.times.decode_s.append(time.time() - t0)
@@ -474,6 +500,19 @@ class RalmEngine:
     def decode_dispatches(self) -> int:
         """LM dispatches issued so far (wave mode: one per wave)."""
         return self.backend.decode_dispatches
+
+    def moe_stats(self) -> Optional[dict]:
+        """An MoE model's routing counters over every decode wave so far
+        (pad rows of a bucket included: what the expert matmuls
+        computed): assignments and experts touched, summed over layers
+        and waves, and the largest one expert's load in any layer of any
+        wave. Read from the device at call time; ``None`` for a dense
+        model."""
+        totals = getattr(self.backend, "moe_totals", None)
+        if totals is None:
+            return None
+        a, touched, top = (int(x) for x in np.asarray(totals))
+        return dict(assignments=a, experts_touched=touched, max_load=top)
 
     # -- constructors -------------------------------------------------------
 

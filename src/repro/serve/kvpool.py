@@ -17,6 +17,14 @@ decode graphs instead of one per wave size. Padding rows all point at
 the scratch slot: they gather/scatter only don't-care state and their
 outputs are dropped, so they never perturb live slots.
 
+A model with multi-head latent attention (``cfg.mla``) keeps one latent
+row per position in place of K and V: ``latent`` [n_layers, P, S,
+kv_lora_rank + qk_rope_head_dim] per class, linear (no ring), bf16 —
+the normed ``c_kv`` and the roped ``k_pe`` of each position, 1152 bytes a
+layer at DeepSeek-V2's 512 + 64, against 16 heads x (192 + 128) x 2 =
+10 KB un-compressed. Alloc, release, rewind, prefill scatter and growth
+treat it as they treat K and V.
+
 The pool grows on demand (slot rows double; the sequence axis extends to
 the longest admitted request) unless constructed with a fixed capacity,
 in which case admission defers until completions free slots — the
@@ -312,19 +320,21 @@ class KVCachePool:
         self.stats.slot_grows += 1
 
     def grow_seq(self, new_max_seq: int) -> None:
-        """Extend the sequence axis of full-length (non-ring) K/V leaves
-        so longer requests fit. Written prefixes keep their positions
-        (slot i of a full cache always holds absolute position i). The
-        new length stays seq-block aligned."""
+        """Extend the sequence axis of full-length (non-ring) K/V and
+        latent leaves so longer requests fit. Written prefixes keep their
+        positions (slot i of a full cache always holds absolute position
+        i). The new length stays seq-block aligned."""
         new_max_seq = self._align(new_max_seq)
         if new_max_seq <= self.max_seq:
             return
         delta = new_max_seq - self.max_seq
         for cls, c in self.caches["classes"].items():
             ring = (cls == "local" and self.cfg.window > 0)
-            if ring or "k" not in c:
+            if ring:
                 continue
-            for key in ("k", "v"):
+            for key in ("k", "v", "latent"):
+                if key not in c:
+                    continue
                 a = c[key]
                 widths = [(0, 0)] * a.ndim
                 widths[2] = (0, delta)

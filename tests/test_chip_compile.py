@@ -11,7 +11,11 @@ to the chip's compiler for a *described* v5e (no chip attached) with
   * ``ivf_scan`` over 32768 lists of 512-d centroids, nprobe 32;
   * ``decode_attn`` at Dec-S widths (8 KV heads of 64) over a 2048-slot
     cache, for waves of 8 and 5 rows;
-  * one whole Dec-S ``decode_wave`` step with the Pallas attention.
+  * one whole Dec-S ``decode_wave`` step with the Pallas attention;
+  * DeepSeek-V2-Lite's widths: the latent mode of ``decode_attn`` (16
+    heads against 576-wide latent rows of a 4096-position pool), the
+    expert ``gmm`` with the program's tiling, and one whole MoE decode
+    wave of its first six layers with both kernels.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -28,7 +32,8 @@ from repro.configs import get_arch
 from repro.core.chamvs import ChamVSConfig
 from repro.core.ivfpq import IVFPQConfig
 from repro.kernels.chamvs_scan.kernel import fused_scan
-from repro.kernels.decode_attn.kernel import fused_decode_attention
+from repro.kernels.decode_attn.kernel import (fused_decode_attention,
+                                              fused_latent_decode_attention)
 from repro.kernels.ivf_scan.kernel import ivf_scan
 from repro.kernels.registry import KernelSpec
 from repro.models import transformer as tf
@@ -114,3 +119,66 @@ def test_dec_s_decode_wave_compiles(one_chip):
         return_hidden=True))
     text = step.lower(place(params), place(caches), *wave).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _dsv2_lite():
+    import dataclasses
+
+    from repro.models.config import ModelConfig
+    return dataclasses.replace(ModelConfig(
+        name="dsv2-lite-6", n_layers=6, d_model=2048, n_heads=16,
+        n_kv_heads=16, d_ff=10944, vocab_size=102400, block="moe",
+        n_experts=64, top_k=6, d_expert=1408, n_shared_experts=2,
+        first_k_dense=1, norm_topk_prob=False, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        yarn_factor=40.0, yarn_original_max_position=4096,
+        yarn_mscale=0.707, yarn_mscale_all_dim=0.707, norm_eps=1e-6))
+
+
+@pytest.mark.parametrize("wave,kv_len", [(8, 2048), (1, 512)])
+def test_latent_decode_attn_compiles(one_chip, wave, kv_len):
+    _compile(lambda q, c, s, p: fused_latent_decode_attention(
+        q, c, s, p, kv_len=kv_len, scale=0.1147, v_dim=512, blk=256,
+        interpret=False), one_chip,
+        ((wave, 16, 576), jnp.float32), ((33, 4096, 576), jnp.bfloat16),
+        ((wave,), jnp.int32), ((wave,), jnp.int32))
+
+
+@pytest.mark.parametrize("rows", [6, 192, 6144])
+def test_expert_gmm_compiles(one_chip, rows):
+    """The three expert matmuls at a one-row wave, a 32-row wave and a
+    1024-token prefill, with the tiling ``moe_dropless`` gives them."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    from repro.models.moe import _gmm_tiles
+    m, tm = _gmm_tiles(rows)
+    for k, n, tiling in ((2048, 1408, (tm, 512, 1408)),
+                         (1408, 2048, (tm, 1408, 512))):
+        _compile(lambda x, w, g: gmm(x, w, g, preferred_element_type=
+                                     jnp.float32, tiling=tiling),
+                 one_chip, ((m, k), jnp.bfloat16), ((64, k, n), jnp.bfloat16),
+                 ((64,), jnp.int32))
+
+
+def test_dsv2_lite_decode_wave_compiles(one_chip, monkeypatch):
+    """A whole MoE serving step of DeepSeek-V2-Lite's first six layers
+    over a 4096-position latent pool: the latent kernel in every layer
+    and the experts through ``gmm`` (steered off this CPU host's
+    ``ragged_dot``)."""
+    from repro.kernels import registry
+    monkeypatch.setattr(registry, "on_cpu", lambda: False)
+    cfg = _dsv2_lite()
+    spec = KernelSpec(backend="pallas", interpret=False, fallback="error")
+    params = jax.eval_shape(lambda: tf.init_params(jax.random.PRNGKey(0),
+                                                   cfg))
+    caches = jax.eval_shape(lambda: tf.init_cache(cfg, 33, max_seq=4096))
+    place = lambda t: jax.tree.map(                       # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    wave = [jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+            for s in ((8, 1), (8,), (8,))]
+    step = jax.jit(lambda p, c, t, s, pos: tf.decode_wave(
+        p, cfg, c, t, s, pos, kv_len=2048, attn_spec=spec,
+        return_hidden=True, return_stats=True))
+    text = step.lower(place(params), place(caches), *wave).compile().as_text()
+    assert "%decode_attn_latent" in text and "%gmm" in text

@@ -4,7 +4,9 @@
     power-of-two bucket; the step-0 logits, the step-0 retrieval query
     and every cache row below the prompt length match an eager prefill
     at the exact length.
-  * Ring (sliding-window), RWKV, hybrid and MoE models are never padded
+  * So does an MoE model, whose serving MoE is dropless (a pad's
+    experts never touch a real token).
+  * Ring (sliding-window), RWKV and hybrid models are never padded
     (their pads would be read), and match the same reference.
   * Admitting three lengths of one bucket builds one program: the first
     admission compiles it, the other two compile nothing.
@@ -93,20 +95,20 @@ def _check_parity(arch, T0, bucket):
     ("dec_s", 5, 16),                        # below the smallest bucket
     ("encdec_s", 20, 32),                    # neutral encoder
     ("qwen2_0_5b", 40, 64),                  # qkv bias
+    ("dbrx_132b", 20, 32),                   # dropless MoE
 ])
 def test_bucketed_prefill_matches_exact(arch, T0, bucket):
-    """A dense, global model pads to its bucket; logits0, hidden0 and
-    the cache rows below T0 match the eager exact-length prefill."""
+    """A dense or MoE global model pads to its bucket; logits0, hidden0
+    and the cache rows below T0 match the eager exact-length prefill."""
     assert pads_unread(get_arch(arch).reduced)
     _check_parity(arch, T0, bucket)
 
 
-@pytest.mark.parametrize("arch", ["gemma3_4b", "rwkv6_3b", "hymba_1_5b",
-                                  "dbrx_132b"])
+@pytest.mark.parametrize("arch", ["gemma3_4b", "rwkv6_3b", "hymba_1_5b"])
 def test_unpaddable_prefill_runs_at_exact_length(arch):
-    """Ring caches, recurrent state and MoE capacity would read the pads:
-    these configurations prefill at the exact length, with the same
-    results as the eager reference."""
+    """Ring caches and recurrent state would read the pads: these
+    configurations prefill at the exact length, with the same results as
+    the eager reference."""
     assert not pads_unread(get_arch(arch).reduced)
     _check_parity(arch, 20, 20)
 
